@@ -1,5 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+# simulated host devices only: never take an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Multi-device *execution* checks (the dry-run only compiles): run the real
 # sharded programs on 8 host devices and assert numerical parity with the
@@ -19,13 +21,14 @@ from repro import sharding
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, reduced
 from repro.data import DataConfig, make_batch
+from repro.launch.mesh import make_mesh, make_sim_mesh
 from repro.models import init_lm
 from repro.optim import OptimizerConfig, init_opt_state
 from repro.runtime import TrainState, make_train_step
 
 
 def mesh_839():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def build(arch: str, mesh, fsdp: bool, cf: float = None):
@@ -103,8 +106,7 @@ def main(mode: str) -> int:
             mgr = CheckpointManager(d)
             mgr.save(2, state, async_=False)
 
-            mesh4 = jax.make_mesh((2, 2), ("data", "model"),
-                                  devices=np.array(jax.devices()[:4]))
+            mesh4 = make_sim_mesh(2, 2)
             cfg4, state4, step_fn4 = build("granite-3-8b", mesh4, fsdp=True)
             psh4 = sharding.param_sharding(state4.params, mesh4, True)
             sh4 = TrainState(psh4, type(state4.opt)(

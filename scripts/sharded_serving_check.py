@@ -1,5 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+# simulated host devices only: never take an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Mesh-sharded paged serving checks (the device count is process-global, so
 # every caller — tests and the `serving_sharded` bench section — runs this

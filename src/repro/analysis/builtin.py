@@ -12,7 +12,7 @@ NG003  tagged low-precision sites do not leak f32 intermediates into the
 NG004  quantize→dequantize round-trips feed a GEMM (anything else is
        cancelling overhead the fake-quant transform never intended)
 NG005  Pallas kernel specs are sound: fusion patterns name real kernels,
-       every kernel takes the ``interpret`` fallback, block shapes are
+       every kernel takes the ``interpret`` keyword, block shapes are
        positive and partial blocks are handled (pad/clamp)
 NG006  no zero-FLOP / zero-byte records (estimator holes in
        ``estimate_flops`` / ``estimate_bytes``)
@@ -263,9 +263,9 @@ def check_kernel_specs(_ctx: Optional[AnalysisContext]):
                 rule="NG005", severity="error", workload="static",
                 where=f"kernel:{name}",
                 message=f"kernel {name!r} does not accept the "
-                        "``interpret`` keyword — it cannot fall back to "
-                        "interpret mode off-TPU and will fail in "
-                        "CPU-only CI",
+                        "``interpret`` keyword — the pallas_interpret "
+                        "backend cannot run it off-TPU, so CPU-only CI "
+                        "fails",
                 fix_hint="route the entry point through _autojit with "
                          "'interpret' in its static argnames")
         for arg, default in spec.block_defaults.items():
@@ -290,7 +290,7 @@ def check_kernel_specs(_ctx: Optional[AnalysisContext]):
                          "or clamp the block to the dim (min(block, dim))")
     # every instantiated attention template spec must be registered: an
     # unregistered variant would execute without any of the static vetting
-    # above (and without the interpret-fallback contract)
+    # above (and without the interpret-keyword contract)
     from repro.kernels import attn_template as _tmpl
     for aspec in _tmpl.instantiated_specs():
         key = _tmpl.kernel_key(aspec)
@@ -473,8 +473,8 @@ def check_tp_collectives(_ctx: Optional[AnalysisContext]):
     if the per-block all-reduces of a tensor-parallel decode fall out of
     COLLECTIVE (or model zero link traffic), the ``serving_sharded``
     section's COLLECTIVE share silently flatlines."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro import nn, sharding
@@ -490,8 +490,8 @@ def check_tp_collectives(_ctx: Optional[AnalysisContext]):
             y = nn.tp_psum(y)        # row-sharded partial-sum reduction
             return nn.tp_vocab_gather(y)   # vocab-sharded logit gather
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                       check_vma=False)
     records = capture(fn, jnp.ones((2, 8), jnp.float32),
                       jnp.ones((8, 8), jnp.float32))
 

@@ -15,10 +15,12 @@ from typing import List, Optional
 
 def _cmd_run(args) -> int:
     from repro.core import report
+    from repro.launch.compile_cache import enable_compile_cache
 
     from .runner import run_bench
 
     tier = "quick" if args.quick else "full"
+    enable_compile_cache()
     try:
         result = run_bench(tier=tier, section_names=args.sections,
                            timeout_scale=args.timeout_scale,

@@ -733,6 +733,9 @@ def sharded_rows(timeout_s: float = 540.0) -> List[dict]:
                PYTHONPATH=os.path.join(repo, "src") + os.pathsep +
                os.environ.get("PYTHONPATH", ""))
     env.pop("XLA_FLAGS", None)     # the script pins its own device count
+    # the child simulates host devices; a parent that touched JAX may
+    # hold the accelerator, so the child must stay off it
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, script, "bench"],
                        capture_output=True, text=True, env=env,
                        timeout=timeout_s)

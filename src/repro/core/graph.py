@@ -7,7 +7,7 @@ control flow resolves) and flattens the jaxpr into a list of
 via the ``ng:`` scope tags emitted by ``repro.nn`` (falling back to the
 primitive-name taxonomy).
 
-Higher-order primitives (``pjit``, ``custom_jvp_call``, ``remat`` ...) are
+Higher-order primitives (``jit``, ``custom_jvp_call``, ``remat`` ...) are
 inlined recursively; ``scan``/``while``/``cond`` bodies are descended into as
 well, with a ``trip_count`` multiplier recorded so FLOP/byte totals are
 loop-aware.
@@ -156,7 +156,7 @@ _LOOP_PRIMS = {"scan", "while", "cond"}
 #: manual-partitioning higher-order prims: the body jaxpr runs per device
 #: with per-shard avals, so descending records the per-device program —
 #: the same per-device convention the roofline uses. Collectives inside
-#: (psum2 / all_gather / ...) become first-class records.
+#: (psum / all_gather / ...) become first-class records.
 _SHARD_MAP_PRIMS = {"shard_map", "smap"}
 
 

@@ -7,7 +7,7 @@ individually, wall-timing every op (``block_until_ready`` per op). This is the
 kernel, exactly like PyTorch eager on CPU in the paper's CPU case studies.
 
 Higher-order primitives in :data:`~repro.core.taxonomy.INLINE_PRIMS` are
-inlined so a ``jax.nn.gelu`` (a ``pjit`` eqn) is timed as its constituent
+inlined so a ``jax.nn.gelu`` (a ``jit`` eqn) is timed as its constituent
 primitives under the enclosing ``ng:`` scope. ``scan``/``while``/``cond`` are
 timed opaquely as single CONTROL (or scope-tagged) records — matching how the
 paper times an FX node whose module contains a loop.

@@ -270,9 +270,8 @@ def _attn_maker(variant: str, window: Optional[int] = None,
     """Micro maker for one generated attention variant.
 
     ``shape`` is (batch, kv_seq, heads, head_dim); the decode variant uses
-    a single query row against the full KV depth.  ``interpret`` is left
-    at its default so the kernel compiles on TPU and interprets on host,
-    exactly like the model-level call sites.
+    a single query row against the full KV depth.  The rows are modeled
+    and timed on the host, so the kernel runs in interpret mode.
     """
     def make(shape, dtype, key):
         from repro.kernels import attn_template
@@ -284,12 +283,13 @@ def _attn_maker(variant: str, window: Optional[int] = None,
         if decode:
             q = _rng(k1, (b, 1, h, d), dtype)
             lengths = jnp.full((b,), s, jnp.int32)
-            return (lambda q, k, v, lengths: fn(q, k, v, lengths)), \
-                (q, k, v, lengths)
+            return (lambda q, k, v, lengths:
+                    fn(q, k, v, lengths, interpret=True)), (q, k, v, lengths)
         q = _rng(k1, shape, dtype)
         if window is not None:
-            return (lambda q, k, v: fn(q, k, v, window=window)), (q, k, v)
-        return (lambda q, k, v: fn(q, k, v)), (q, k, v)
+            return (lambda q, k, v: fn(q, k, v, window=window,
+                                       interpret=True)), (q, k, v)
+        return (lambda q, k, v: fn(q, k, v, interpret=True)), (q, k, v)
     return make
 
 

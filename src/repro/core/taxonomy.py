@@ -173,10 +173,12 @@ _reg(
 )
 _reg(
     OpGroup.COLLECTIVE,
-    # "psum2" is what jax.lax.psum binds to inside a shard_map body
-    # (jax >= 0.4.3x); the plain "psum" name survives in pmap-era jaxprs
-    "psum", "psum2", "all_gather", "all_to_all", "ppermute", "pmax", "pmin",
-    "psum_scatter", "reduce_scatter", "axis_index", "pbroadcast",
+    # jax.lax.psum binds "psum" in a shard_map body with check_vma=False
+    # and "psum_invariant" under the strict check; "reshard" moves an array
+    # to another sharding
+    "psum", "psum_invariant", "all_gather", "all_to_all", "ppermute",
+    "pmax", "pmin", "psum_scatter", "reduce_scatter", "axis_index",
+    "pbroadcast", "reshard",
 )
 
 #: Every jaxpr primitive registered under COLLECTIVE — the set the capture
@@ -187,7 +189,7 @@ COLLECTIVE_PRIMS = frozenset(
 )
 _reg(
     OpGroup.CONTROL,
-    "scan", "while", "cond", "pjit", "closed_call", "core_call", "remat",
+    "scan", "while", "cond", "jit", "closed_call", "core_call", "remat",
     "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "custom_lin",
     "shard_map", "smap", "named_call", "pvary",
@@ -201,7 +203,7 @@ _reg(OpGroup.FUSED, "pallas_call")
 #: their sub-jaxpr under the parent scope) rather than timing opaquely.
 INLINE_PRIMS = frozenset(
     {
-        "pjit", "closed_call", "core_call", "named_call", "remat",
+        "jit", "closed_call", "core_call", "named_call", "remat",
         "checkpoint", "custom_jvp_call", "custom_vjp_call",
         "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
     }

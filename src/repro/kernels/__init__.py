@@ -3,8 +3,7 @@
 Layout (per assignment):
     <name>.py  pl.pallas_call + explicit BlockSpec VMEM tiling
     ops.py     jit'd wrappers with the interpret switch (nn backend);
-               interpret auto-defaults to True when no TPU is attached
-               (``REPRO_PALLAS_INTERPRET`` overrides)
+               Mosaic by default, interpret mode only when asked for
     ref.py     pure-jnp oracles (the allclose ground truth)
 
 Kernels: norms (rmsnorm / layernorm / fused add+rmsnorm / fused

@@ -24,32 +24,34 @@ from jax.experimental import pallas as pl
 
 def _nms_kernel(x1_ref, y1_ref, x2_ref, y2_ref, valid_ref, keep_ref, *,
                 n: int, iou_threshold: float):
-    x1 = x1_ref[0].astype(jnp.float32)       # (N,)
-    y1 = y1_ref[0].astype(jnp.float32)
-    x2 = x2_ref[0].astype(jnp.float32)
-    y2 = y2_ref[0].astype(jnp.float32)
-    valid = valid_ref[0] != 0
+    x1 = x1_ref[...].astype(jnp.float32)     # (1, N) lane rows
+    y1 = y1_ref[...].astype(jnp.float32)
+    x2 = x2_ref[...].astype(jnp.float32)
+    y2 = y2_ref[...].astype(jnp.float32)
+    valid = (valid_ref[...] != 0).astype(jnp.int32)
     area = jnp.maximum(x2 - x1, 0) * jnp.maximum(y2 - y1, 0)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+    def lane(v, i):
+        # lane i of a (1, N) row as a (1, 1) value: Mosaic cannot slice an
+        # in-register vector at a dynamic offset, so select and reduce
+        return jnp.sum(jnp.where(idx == i, v, 0), axis=1, keepdims=True)
 
     def body(i, keep):
-        bx1 = jax.lax.dynamic_index_in_dim(x1, i, keepdims=False)
-        by1 = jax.lax.dynamic_index_in_dim(y1, i, keepdims=False)
-        bx2 = jax.lax.dynamic_index_in_dim(x2, i, keepdims=False)
-        by2 = jax.lax.dynamic_index_in_dim(y2, i, keepdims=False)
+        bx1, by1 = lane(x1, i), lane(y1, i)
+        bx2, by2 = lane(x2, i), lane(y2, i)
         barea = jnp.maximum(bx2 - bx1, 0) * jnp.maximum(by2 - by1, 0)
         iw = jnp.maximum(jnp.minimum(x2, bx2) - jnp.maximum(x1, bx1), 0)
         ih = jnp.maximum(jnp.minimum(y2, by2) - jnp.maximum(y1, by1), 0)
         inter = iw * ih
         union = area + barea - inter
         iou = jnp.where(union > 0, inter / union, 0.0)
-        alive = (jax.lax.dynamic_index_in_dim(keep, i, keepdims=False)
-                 & jax.lax.dynamic_index_in_dim(valid, i, keepdims=False))
+        alive = lane(keep * valid, i) > 0
         suppress = (iou > iou_threshold) & (idx > i) & alive
-        return keep & ~suppress
+        return jnp.where(suppress, 0, keep)
 
     keep = jax.lax.fori_loop(0, n, body, valid)
-    keep_ref[0] = keep.astype(keep_ref.dtype)
+    keep_ref[...] = keep.astype(keep_ref.dtype)
 
 
 def nms_sorted(boxes_sorted, valid, iou_threshold: float = 0.5,

@@ -1,29 +1,21 @@
 """jit'd public wrappers over the Pallas kernels (the ``repro.nn`` backend).
 
-Every wrapper takes a keyword-only ``interpret: bool | None``:
+Every wrapper takes a keyword-only ``interpret: bool = False``:
 
-* ``None`` (the default) resolves via :func:`default_interpret` — interpret
-  mode whenever no TPU is attached, so the kernels (and the fused model
-  paths built on them) exercise end-to-end in CPU-only CI without every
-  call site threading the flag. ``REPRO_PALLAS_INTERPRET=0|1`` overrides
-  the auto-detection either way.
-* ``True`` runs the kernel body in Python on CPU (validation mode).
-* ``False`` emits the real Mosaic TPU kernel.
+* ``False`` (the default) emits the real Mosaic TPU kernel, which needs a
+  TPU; lowering it for any other platform fails.
+* ``True`` runs the kernel body in Python (validation mode, any host).
 
-Resolution happens *outside* the jit (``interpret`` is a static argname),
-so flipping the environment variable between calls retraces instead of
-reusing a stale cache entry. Each public name is :func:`_autojit` applied
-to the raw kernel entry point — one place owns the contract, so a new
-kernel cannot accidentally skip the auto-interpret default. Signatures
-match the ``repro.nn`` call sites so ``nn.set_backend("pallas"/
-"pallas_interpret")`` swaps implementations without touching model code.
+Nothing picks interpret mode for the caller: host-only callers pass
+``interpret=True`` themselves, and ``nn.set_backend("pallas_interpret")``
+does so for every model call site. Signatures match the ``repro.nn``
+call sites so ``nn.set_backend("pallas"/"pallas_interpret")`` swaps
+implementations without touching model code.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
-import os
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import jax
@@ -35,41 +27,13 @@ from repro.kernels import rope as _rope
 from repro.kernels import softmax_xent as _xent
 from repro.kernels import swiglu as _glu
 
-#: env override for the CI auto-default ("1"/"true" forces interpret mode,
-#: "0"/"false" forces real Mosaic lowering; empty counts as unset)
-INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
-
-
-def default_interpret() -> bool:
-    """True when the Pallas kernels should run in interpret mode here.
-
-    No TPU attached -> interpret (the CPU-only CI / laptop case);
-    ``REPRO_PALLAS_INTERPRET`` overrides in either direction. An empty
-    value counts as unset (the CI-YAML way to clear a variable), falling
-    through to the TPU auto-detection.
-    """
-    env = os.environ.get(INTERPRET_ENV)
-    if env is not None and env.strip():
-        return env.strip().lower() not in ("0", "false", "no")
-    return jax.default_backend() != "tpu"
-
-
-def _resolve(interpret: Optional[bool]) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
-
 
 def _autojit(kernel_fn, static):
     """Public wrapper factory: jit ``kernel_fn`` with ``static`` argnames
-    and resolve the keyword-only ``interpret`` flag before the jit sees
-    it (``interpret`` must be in ``static``)."""
-    assert "interpret" in static
-    jitted = jax.jit(kernel_fn, static_argnames=static)
-
-    @functools.wraps(kernel_fn)
-    def wrapper(*args, interpret: Optional[bool] = None, **kwargs):
-        return jitted(*args, interpret=_resolve(interpret), **kwargs)
-
-    return wrapper
+    (``interpret`` must be among them, so both modes cache separately)."""
+    if "interpret" not in static:
+        raise ValueError(f"{kernel_fn.__name__}: 'interpret' must be static")
+    return jax.jit(kernel_fn, static_argnames=static)
 
 
 rms_norm = _autojit(_norms.rms_norm,
@@ -160,7 +124,7 @@ def register_template_kernel(spec, raw_fn, static) -> Callable:
     """Auto-registration hook for :func:`attn_template.make_attention`.
 
     Wraps the generated raw entry point in :func:`_autojit` (so every
-    variant inherits the interpret-resolution contract) and records it in
+    variant takes the same static ``interpret`` flag) and records it in
     ``KERNEL_SPECS`` under ``attn_template:<name>`` at spec-instantiation
     time — nglint NG005 then vets the variant like any hand-written
     kernel, and flags instantiated specs missing from this table.

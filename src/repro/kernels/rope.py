@@ -27,7 +27,9 @@ from jax.experimental import pallas as pl
 def _rope_kernel(x_ref, p_ref, o_ref, *, base: float):
     x = x_ref[...].astype(jnp.float32)          # (rows, H, rot)
     half = x.shape[-1] // 2
-    idx = jax.lax.broadcasted_iota(jnp.float32, (1, 1, half), 2)
+    # Mosaic builds iotas in integer registers only
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, half), 2).astype(
+        jnp.float32)
     freq = base ** (-idx / half)
     theta = p_ref[...][:, :, None] * freq       # (rows, 1, half)
     cos = jnp.cos(theta)

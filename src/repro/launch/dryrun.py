@@ -1,12 +1,16 @@
 import os
+import tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU-only compile tool: it (and every --sweep child, which inherits this
+# environment) must never take an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # The roofline analyzer reads the post-SPMD-partitioning (pre-optimization)
 # module: it has true dtypes (XLA:CPU's optimized module legalizes every
 # bf16 buffer to f32 — 2x inflated and misleading for a TPU roofline),
 # per-device shapes, and materialized collectives. Dumped per-process.
 _DUMP_DIR = os.environ.get("REPRO_DUMP_DIR") or os.path.join(
-    "/tmp", f"repro_xla_dump_{os.getpid()}")
+    tempfile.gettempdir(), f"repro_xla_dump_{os.getpid()}")
 os.environ["XLA_FLAGS"] += (
     f" --xla_dump_to={_DUMP_DIR} --xla_dump_hlo_pass_re=spmd-partitioning")
 
@@ -14,8 +18,8 @@ os.environ["XLA_FLAGS"] += (
 # (architecture x input shape) cell on the production meshes with
 # ShapeDtypeStruct inputs — no allocation — and record memory_analysis /
 # cost_analysis / trip-aware collective bytes for the roofline (deliverable
-# g). The two lines above MUST precede any jax import: XLA locks the host
-# platform device count at first init.
+# g). The environment lines above MUST precede any jax import: XLA locks
+# the platform and the host device count at first init.
 #
 # Usage:
 #   python -m repro.launch.dryrun --arch stablelm-3b --shape train_4k

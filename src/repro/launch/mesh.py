@@ -9,18 +9,28 @@ lock the real 1-device topology in).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    # Auto axes: the logical-rule sharding (with_sharding_constraint,
+    # NamedSharding placements) and the manual-TP shard_map both expect
+    # the partitioner to propagate shardings, not the Explicit-axis mode
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi_pod adds the 2-pod DCI axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1x1 mesh over the real local device (smoke tests / examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_sim_mesh(data: int = 1, model: int = 1):
@@ -42,9 +52,8 @@ def make_sim_mesh(data: int = 1, model: int = 1):
             f"--xla_force_host_platform_device_count={need} in the "
             f"environment BEFORE the first jax import (the device count "
             f"locks at jax init; see scripts/sharded_serving_check.py).")
-    import numpy as np
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=np.array(jax.devices()[:need]))
+    return make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[:need])
 
 
 def mesh_chips(mesh) -> int:

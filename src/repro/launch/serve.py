@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_lm
 from repro.serving import Engine
 
@@ -26,6 +27,7 @@ def main() -> int:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -18,6 +18,7 @@ import jax
 
 from repro.configs import get_config, reduced
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_lm
 from repro.optim import OptimizerConfig
 from repro.runtime import Trainer
@@ -40,6 +41,7 @@ def main() -> int:
                     help="train the reduced config (CPU-scale)")
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

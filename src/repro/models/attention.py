@@ -437,7 +437,7 @@ def _attention_impl(q, k, v, cfg: ModelConfig, window, q_offset: int = 0):
         from repro.kernels import ops as kops
         return kops.flash_attention(
             q, k, v, causal=cfg.causal, window=window, q_offset=q_offset,
-            interpret=None if backend == "pallas" else True)
+            interpret=nn.kernel_interpret())
     return flash_attention_jnp(
         q, k, v, causal=cfg.causal, window=window, q_offset=q_offset,
         chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
@@ -561,7 +561,7 @@ def attn_decode(params, x, cfg: ModelConfig, kind: str, cache: dict,
         from repro.kernels import ops as kops
         o = kops.attn_decode_template(
             q, k, v, lengths, softcap=cfg.attn_logit_softcap,
-            interpret=None if backend == "pallas" else True)
+            interpret=nn.kernel_interpret())
         o = o.reshape(b, 1, hq * hd).astype(x.dtype)
         return nn.linear(o, params["wo"].astype(x.dtype)), new_cache
 
@@ -677,7 +677,7 @@ def mla_forward(params, x, cfg: ModelConfig, positions):
         from repro.kernels import ops as kops
         out = kops.flash_attention(
             q, k, v, causal=cfg.causal,
-            interpret=None if backend == "pallas" else True)
+            interpret=nn.kernel_interpret())
     else:
         out = flash_attention_jnp(q, k, v, causal=cfg.causal,
                                   chunk_q=cfg.attn_chunk_q,
@@ -748,7 +748,7 @@ def mla_decode(params, x, cfg: ModelConfig, cache: dict, pos):
             from repro.kernels import ops as kops
             ctx = kops.attn_decode_template(
                 q_eff, k_eff, v_eff, lengths, scale=scale,
-                interpret=None if backend == "pallas" else True)
+                interpret=nn.kernel_interpret())
     else:
         with jax.named_scope(nn.scope_tag(OpGroup.GEMM, "attn_qk")):
             s = (jnp.einsum("bqhr,btr->bhqt", q_lat, c,

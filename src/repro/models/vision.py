@@ -202,7 +202,7 @@ def _refine_boxes(xp, tokens, idx, top_b, stride: float, cfg: ModelConfig):
     if backend != "jnp":
         from repro.kernels import ops as kops
         att = kops.attn_full_template(
-            q, kk, vv, interpret=None if backend == "pallas" else True)
+            q, kk, vv, interpret=nn.kernel_interpret())
     else:
         att = flash_attention_jnp(q, kk, vv, causal=False)
     att = nn.linear(nn.merge_heads(att), xp["wo"].astype(tokens.dtype))

@@ -9,9 +9,8 @@ torch.fx at ``nn.Module`` boundaries.
 A process-global backend switch selects the implementation:
 
     "jnp"              pure jax.numpy (reference; used for dry-run/compile)
-    "pallas"           fused Pallas TPU kernels where available (real TPU;
-                       auto-falls back to interpret mode off-TPU — see
-                       repro.kernels.ops.default_interpret)
+    "pallas"           fused Pallas TPU kernels where available, lowered
+                       through Mosaic; needs a TPU and raises without one
     "pallas_interpret" Pallas kernels in interpret mode (CPU correctness)
 
 Ops without a Pallas kernel always use the jnp path.
@@ -67,13 +66,21 @@ def _kernels():
     return kops
 
 
-def _interpret():
+def kernel_interpret() -> bool:
     """Per-call interpret flag for the kernel backends.
 
-    ``pallas_interpret`` forces interpret mode; plain ``pallas`` passes
-    None so ``repro.kernels.ops`` auto-detects (interpret off-TPU).
+    ``pallas_interpret`` runs the kernel bodies in Python on any host;
+    ``pallas`` emits the real Mosaic kernels, so it needs a TPU and raises
+    rather than quietly interpreting on another platform.
     """
-    return True if _BACKEND == "pallas_interpret" else None
+    if _BACKEND == "pallas_interpret":
+        return True
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"nn backend 'pallas' emits Mosaic TPU kernels but the default "
+            f"JAX backend is {jax.default_backend()!r}; use "
+            f"'pallas_interpret' to run the kernels in interpret mode")
+    return False
 
 
 #: process-global fusion switch (the execution half of repro.core.fusion):
@@ -194,7 +201,7 @@ def tagged(group: OpGroup, name: str):
 def layer_norm(x, scale, bias, eps: float = 1e-5):
     if _BACKEND != "jnp":
         return _kernels().layer_norm(x, scale, bias, eps=eps,
-                                     interpret=_interpret())
+                                     interpret=kernel_interpret())
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
@@ -208,7 +215,7 @@ def rms_norm(x, scale, eps: float = 1e-6, zero_centered: bool = False):
     if _BACKEND != "jnp":
         return _kernels().rms_norm(x, scale, eps=eps,
                                    zero_centered=zero_centered,
-                                   interpret=_interpret())
+                                   interpret=kernel_interpret())
     xf = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     y = xf * jax.lax.rsqrt(ms + eps)
@@ -224,7 +231,7 @@ def fused_add_rms_norm(x, residual, scale, eps: float = 1e-6,
     if _BACKEND != "jnp":
         return _kernels().fused_add_rms_norm(
             x, residual, scale, eps=eps, zero_centered=zero_centered,
-            interpret=_interpret())
+            interpret=kernel_interpret())
     r = (x.astype(jnp.float32) + residual.astype(jnp.float32)).astype(x.dtype)
     return rms_norm(r, scale, eps=eps, zero_centered=zero_centered), r
 
@@ -261,7 +268,7 @@ def swiglu(gate, up):
         return _fused_swiglu(gate, up)
     if _BACKEND != "jnp":
         return _kernels().swiglu(gate, up,
-                                 interpret=_interpret())
+                                 interpret=kernel_interpret())
     return (gate * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(gate.dtype)
             ) * up
 
@@ -549,7 +556,7 @@ def _fused_add_rms_norm(x, residual, scale, eps: float = 1e-6,
     if _BACKEND != "jnp":
         return _kernels().fused_add_rms_norm(
             x, residual, scale, eps=eps, zero_centered=zero_centered,
-            interpret=_interpret())
+            interpret=kernel_interpret())
     return _ref().fused_add_rms_norm(x, residual, scale, eps=eps,
                                      zero_centered=zero_centered)
 
@@ -558,7 +565,7 @@ def _fused_add_rms_norm(x, residual, scale, eps: float = 1e-6,
 def _fused_add_layer_norm(x, residual, scale, bias, eps: float = 1e-5):
     if _BACKEND != "jnp":
         return _kernels().fused_add_layer_norm(
-            x, residual, scale, bias, eps=eps, interpret=_interpret())
+            x, residual, scale, bias, eps=eps, interpret=kernel_interpret())
     return _ref().fused_add_layer_norm(x, residual, scale, bias, eps=eps)
 
 
@@ -599,7 +606,7 @@ def dequant_add_rms_norm(q, qscale, residual, scale, eps: float = 1e-6,
     if _BACKEND != "jnp":
         return _kernels().dequant_add_rms_norm(
             q, qscale, residual, scale, eps=eps,
-            zero_centered=zero_centered, interpret=_interpret())
+            zero_centered=zero_centered, interpret=kernel_interpret())
     return _ref().dequant_add_rms_norm(q, qscale, residual, scale, eps=eps,
                                        zero_centered=zero_centered)
 
@@ -607,14 +614,14 @@ def dequant_add_rms_norm(q, qscale, residual, scale, eps: float = 1e-6,
 @tagged(OpGroup.FUSED, "fused_swiglu")
 def _fused_swiglu(gate, up):
     if _BACKEND != "jnp":
-        return _kernels().swiglu(gate, up, interpret=_interpret())
+        return _kernels().swiglu(gate, up, interpret=kernel_interpret())
     return _ref().swiglu(gate, up)
 
 
 @tagged(OpGroup.FUSED, "fused_geglu")
 def _fused_geglu(gate, up):
     if _BACKEND != "jnp":
-        return _kernels().geglu(gate, up, interpret=_interpret())
+        return _kernels().geglu(gate, up, interpret=kernel_interpret())
     return jax.nn.gelu(gate.astype(jnp.float32),
                        approximate=True).astype(gate.dtype) * up
 
@@ -624,7 +631,7 @@ def _fused_rope(x, positions, base: float = 10000.0, fraction: float = 1.0):
     if _BACKEND != "jnp":
         return _kernels().fused_rope(x, positions, base=base,
                                      fraction=fraction,
-                                     interpret=_interpret())
+                                     interpret=kernel_interpret())
     return _ref().rope(x, positions, base=base, fraction=fraction)
 
 
@@ -652,7 +659,7 @@ def fused_attn_decode(q, k, v, lengths, scale: Optional[float] = None,
     if _BACKEND != "jnp":
         return _kernels().attn_decode_template(
             q, k, v, lengths, scale=scale, softcap=softcap,
-            interpret=_interpret())
+            interpret=kernel_interpret())
     return _ref().decode_attention(q, k, v, lengths, scale=scale,
                                    softcap=softcap)
 
@@ -762,7 +769,7 @@ def nms(boxes, scores, iou_threshold: float = 0.5,
     if _BACKEND != "jnp":
         return _kernels().nms(boxes, scores, iou_threshold=iou_threshold,
                               score_threshold=score_threshold,
-                              interpret=_interpret())
+                              interpret=kernel_interpret())
     n = boxes.shape[0]
     order = jnp.argsort(-scores)
     b = boxes[order]

@@ -249,11 +249,10 @@ def _scatter_tree(pools: dict, caches: dict, table_row, start, lo, hi,
 # ---------------------------------------------------------------------------
 
 def _tp_shard_map(body, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-    # check_rep=False: psum-produced outputs defeat static replication
+    # check_vma=False: psum-produced outputs defeat static replication
     # inference (and with it, psum binds as the plain `psum` primitive)
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _tp_cache_struct_specs(cfg: ModelConfig, max_len: int, tp: int):

@@ -2,8 +2,8 @@
 
 Covers the four mask fragments (causal / window / full-cross / decode-1q),
 odd sequence lengths, GQA groups, dv != dk, softcap, the RoPE fragment,
-the fully-masked-row epilogue guard, the ``REPRO_PALLAS_INTERPRET``
-override, the NG005 registration cross-check, and model-level routing
+the fully-masked-row epilogue guard, the explicit-interpret contract,
+the NG005 registration cross-check, and model-level routing
 (attn_decode / mla_decode / detector query refinement) across backends.
 """
 
@@ -178,14 +178,15 @@ def test_bf16_parity(rng):
                                np.asarray(want, np.float32), atol=5e-2)
 
 
-def test_interpret_env_override(rng, monkeypatch):
-    # REPRO_PALLAS_INTERPRET=1 must route the default (interpret=None)
-    # template call through interpret mode off-TPU — the CI configuration
-    monkeypatch.setenv(ops.INTERPRET_ENV, "1")
+def test_interpret_env_override(rng):
+    # the template interprets only when asked, and defaults to the Mosaic
+    # kernel, which cannot lower for the CPU
     q, k, v = _qkv(rng, 1, 16, 16, 2, 2, 32)
-    got = T.get("causal")(q, k, v)
+    got = T.get("causal")(q, k, v, interpret=True)
     want = ref.attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+    with pytest.raises(ValueError, match="interpret"):
+        T.get("causal")(q, k, v)
 
 
 # ---------------------------------------------------------------------------

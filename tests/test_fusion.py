@@ -329,30 +329,42 @@ def test_engine_fused_decode_matches_unfused():
 
 
 # ---------------------------------------------------------------------------
-# kernels/ops interpret auto-default (CI-runnable satellite)
+# kernels/ops interpret contract: interpret mode only when asked for
 # ---------------------------------------------------------------------------
 
-def test_default_interpret_env_override(monkeypatch):
+def test_default_interpret_env_override():
+    # no environment knob and no auto-detection: every public kernel
+    # defaults to the Mosaic kernel
+    import inspect
+
     from repro.kernels import ops
 
-    monkeypatch.delenv(ops.INTERPRET_ENV, raising=False)
-    assert ops.default_interpret() == (jax.default_backend() != "tpu")
-    monkeypatch.setenv(ops.INTERPRET_ENV, "0")
-    assert ops.default_interpret() is False
-    monkeypatch.setenv(ops.INTERPRET_ENV, "1")
-    assert ops.default_interpret() is True
-    # empty value == unset (how CI YAML clears a variable): auto-detect
-    monkeypatch.setenv(ops.INTERPRET_ENV, "")
-    assert ops.default_interpret() == (jax.default_backend() != "tpu")
+    for name, spec in ops.KERNEL_SPECS.items():
+        param = inspect.signature(spec.fn).parameters["interpret"]
+        assert param.default is False, name
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
+    with pytest.raises(ValueError, match="interpret"):
+        ops.rms_norm(x, W64)       # Mosaic cannot lower for the CPU
 
 
 def test_pallas_backend_runs_without_tpu():
-    # nn "pallas" backend auto-interprets off-TPU: no flag threading needed
+    # off-TPU the kernels run only under the explicit interpret backend
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
-    with nn.backend("pallas"):
+    with nn.backend("pallas_interpret"):
+        assert nn.kernel_interpret() is True
         got = nn.rms_norm(x, W64)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(nn.rms_norm(x, W64)), atol=1e-5)
+
+
+def test_pallas_backend_off_tpu_raises():
+    # "pallas" means real Mosaic kernels: without a TPU it refuses and
+    # names the interpret backend instead of quietly interpreting
+    assert jax.default_backend() != "tpu"
+    x = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
+    with nn.backend("pallas"):
+        with pytest.raises(RuntimeError, match="pallas_interpret"):
+            nn.rms_norm(x, W64)
 
 
 # ---------------------------------------------------------------------------

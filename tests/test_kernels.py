@@ -254,11 +254,12 @@ def test_nms_parity_none_suppressed(rng):
     _assert_nms_parity(*_random_boxes(rng, 64), iou_threshold=1.5)
 
 
-def test_nms_parity_under_interpret_env(rng, monkeypatch):
-    # REPRO_PALLAS_INTERPRET=1 must route the default (interpret=None)
-    # call through interpret mode off-TPU — the CI configuration
-    monkeypatch.setenv(ops.INTERPRET_ENV, "1")
+def test_nms_parity_under_interpret_env(rng):
+    # only an explicit interpret=True interprets; the default is the
+    # Mosaic kernel, which cannot lower for the CPU
     boxes, scores = _random_boxes(rng, 200)
-    got = ops.nms(boxes, scores, iou_threshold=0.4)
+    got = ops.nms(boxes, scores, iou_threshold=0.4, interpret=True)
     want = ref.nms(boxes, scores, iou_threshold=0.4)
     assert bool(jnp.all(got == want))
+    with pytest.raises(ValueError, match="interpret"):
+        ops.nms(boxes, scores, iou_threshold=0.4)

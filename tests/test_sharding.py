@@ -8,12 +8,11 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro import sharding as sh
 from repro.configs import get_config, reduced
+from repro.launch.mesh import make_host_mesh
 from repro.models import init_lm
 
-# keyword-free (axis-name, size) pair form — the only constructor shape
-# current JAX accepts (positional dims + names raises TypeError)
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_spec_for_basic_tp():
@@ -106,7 +105,7 @@ def test_shard_is_noop_without_rules():
 
 
 def test_use_rules_context():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     x = jnp.ones((4, 4))
     with sh.use_rules(mesh, fsdp=False):
         y = sh.shard(x, "batch", "seq")  # 1x1 mesh: fully replicated
