@@ -1,0 +1,21 @@
+"""The decode attention kernel's share of its roofline.
+
+Device time of the ops under ``ng:fused:fused_attn_decode`` in the
+paged-decode program, against the least time of that attention: each live
+K/V row read once per KV head, the query read and the output written.
+"""
+
+from chipbench.trace import ATTN_DECODE, DECODE
+
+
+def read(ctx):
+    d, c, pk = ctx.drive, ctx.costs, ctx.peaks
+    least = 0.0
+    for r in d.steps:
+        if d.t0 <= r.start < d.end and r.decode_positions:
+            flops, nbytes = c.decode_attention_cost(ctx.conf,
+                                                    r.decode_positions)
+            least += max(flops / pk["bf16_flops_per_s"],
+                         nbytes / pk["hbm_bytes_per_s"])
+    secs = ctx.trace.site_time(DECODE, ATTN_DECODE)
+    return 100.0 * least / secs if least and secs else None
