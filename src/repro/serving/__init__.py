@@ -23,6 +23,12 @@ TPU serving engines pad the batch, not the program):
 * ``EngineStats`` extends throughput accounting with per-request latency:
   time-to-first-token, queue wait, and per-token decode latency.
 
+Every timed region of the engine is one ``Engine._span``: a
+``jax.profiler`` trace span named ``serve.<region>`` (recorded only while a
+profiler trace runs, on the device planes' clock) that, where it names an
+``EngineStats`` field, adds the region's time on the engine's ``clock`` to
+it. ``Request`` timestamps read the same clock.
+
 Token accounting: every request's first output token comes from the prefill
 argmax and is counted in ``EngineStats.first_tokens``; every token emitted
 by a decode step is counted in ``EngineStats.decode_tokens`` at the moment
@@ -32,6 +38,7 @@ number of emitted decode tokens exactly.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, List, Optional, Sequence
@@ -284,36 +291,39 @@ class Engine:
             # (enqueue) to the FIRST admission.
             req.admit_t = self.clock()
         plen = len(req.prompt)
-        bucket = self._bucket(plen)
-        toks = np.full((1, bucket), self.pad_id, np.int32)
-        toks[0, :plen] = req.prompt          # right-padded
-        t0 = time.perf_counter()
-        logits, one = self._prefill(self.params, jnp.asarray(toks),
-                                    jnp.full((1,), plen, jnp.int32))
-        first = self._first_token(logits)
-        live = not ((self.eos_id is not None and first == self.eos_id)
-                    or req.max_new_tokens <= 1
-                    or plen >= self.max_len)
-        if live:
-            # splice the single-row caches into the slot; block on the
-            # result so this full-cache write is charged to the prefill
-            # phase, not the next decode step's timed region. A request
-            # finishing at admission never needs its caches.
-            self._caches = self._insert(self._caches, one, slot)
-            jax.block_until_ready(self._caches)
-        self.stats.prefill_s += time.perf_counter() - t0
-        self.stats.prefill_tokens += plen
+        with self._span("serve.admit", uid=req.uid, prompt_len=plen,
+                        cached_len=0, path="cold"):
+            bucket = self._bucket(plen)
+            toks = np.full((1, bucket), self.pad_id, np.int32)
+            toks[0, :plen] = req.prompt      # right-padded
+            with self._span("serve.prefill", "prefill_s", uid=req.uid,
+                            bucket=bucket):
+                logits, one = self._prefill(self.params, jnp.asarray(toks),
+                                            jnp.full((1,), plen, jnp.int32))
+                first = self._first_token(logits)
+            live = not ((self.eos_id is not None and first == self.eos_id)
+                        or req.max_new_tokens <= 1
+                        or plen >= self.max_len)
+            if live:
+                # splice the single-row caches into the slot; block on the
+                # result so this full-cache write is charged to the prefill
+                # phase, not the next decode step's timed region. A request
+                # finishing at admission never needs its caches.
+                with self._span("serve.scatter", "prefill_s", uid=req.uid):
+                    self._caches = self._insert(self._caches, one, slot)
+                    jax.block_until_ready(self._caches)
+            self.stats.prefill_tokens += plen
 
-        req.output.append(first)
-        self.stats.first_tokens += 1
-        req.first_token_t = self.clock()
-        if not live:
-            self._finish(req)
-            return False
-        self.slots[slot] = req
-        self._pos[slot] = plen               # next write index == prompt end
-        self._cur[slot] = first
-        return True
+            req.output.append(first)
+            self.stats.first_tokens += 1
+            req.first_token_t = self.clock()
+            if not live:
+                self._finish(req)
+                return False
+            self.slots[slot] = req
+            self._pos[slot] = plen           # next write index == prompt end
+            self._cur[slot] = first
+            return True
 
     def _admit_free_slots(self) -> List[Request]:
         """Fill every free slot from the queue; returns requests that
@@ -347,6 +357,26 @@ class Engine:
         prime the jit caches with dummy requests, then measure cleanly."""
         self.stats = EngineStats()
 
+    @contextlib.contextmanager
+    def _span(self, name: str, stat: Optional[str] = None, **args):
+        """One region of the engine's work, the engine's only timer.
+
+        Opens a ``jax.profiler.TraceAnnotation`` named ``name`` with
+        ``args``; it is recorded only while a profiler trace runs, and then
+        on the clock of the device planes. Where ``stat`` names an
+        ``EngineStats`` field, the region's time on ``self.clock`` is added
+        to it. Yields the annotation: ``set_metadata`` adds args that are
+        known only at the region's end.
+        """
+        with jax.profiler.TraceAnnotation(name, **args) as ann:
+            if stat is None:
+                yield ann
+                return
+            t0 = self.clock()
+            yield ann
+            setattr(self.stats, stat,
+                    getattr(self.stats, stat) + self.clock() - t0)
+
     # -- stepping ----------------------------------------------------------
     @property
     def active(self) -> int:
@@ -367,29 +397,33 @@ class Engine:
         if self.active == 0:
             return finished
 
-        t0 = time.perf_counter()
-        self.key, k = jax.random.split(self.key)
-        nxt, self._caches = self._decode(
-            self.params, jnp.asarray(self._cur), jnp.asarray(self._pos),
-            self._caches, k)
-        nxt_host = np.asarray(jax.block_until_ready(nxt))
-        self.stats.decode_s += time.perf_counter() - t0
-        self.stats.decode_steps += 1
-
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue            # pad-fed dead slot: output discarded
-            tok = int(nxt_host[i])
-            r.output.append(tok)
-            self.stats.decode_tokens += 1    # counted where emitted
-            self._pos[i] += 1
-            self._cur[i] = tok
-            if (self.eos_id is not None and tok == self.eos_id) \
-                    or len(r.output) >= r.max_new_tokens \
-                    or self._pos[i] >= self.max_len:
-                self._finish(r)
-                finished.append(r)
-                self._free(i)
+        with self._span("serve.decode", live=self.active):
+            with self._span("serve.decode.prep", "decode_s"):
+                self.key, k = jax.random.split(self.key)
+                cur, pos = jnp.asarray(self._cur), jnp.asarray(self._pos)
+            with self._span("serve.decode.dispatch", "decode_s"):
+                nxt, self._caches = self._decode(self.params, cur, pos,
+                                                 self._caches, k)
+            with self._span("serve.decode.wait", "decode_s"):
+                nxt_host = np.asarray(jax.block_until_ready(nxt))
+            self.stats.decode_steps += 1
+            with self._span("serve.retire") as span:
+                n = len(finished)
+                for i, r in enumerate(self.slots):
+                    if r is None:
+                        continue        # pad-fed dead slot: output discarded
+                    tok = int(nxt_host[i])
+                    r.output.append(tok)
+                    self.stats.decode_tokens += 1    # counted where emitted
+                    self._pos[i] += 1
+                    self._cur[i] = tok
+                    if (self.eos_id is not None and tok == self.eos_id) \
+                            or len(r.output) >= r.max_new_tokens \
+                            or self._pos[i] >= self.max_len:
+                        self._finish(r)
+                        finished.append(r)
+                        self._free(i)
+                span.set_metadata(finished=len(finished) - n)
         return finished
 
     def run(self) -> List[Request]:

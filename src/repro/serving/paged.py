@@ -32,7 +32,6 @@ garbage that no masked read ever consumes.
 
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -561,13 +560,18 @@ class PagedEngine(Engine):
         if req.admit_t == 0.0:
             req.admit_t = self.clock()
         plen = len(req.prompt)
-        cached_len, reused = 0, []
-        if self.prefix_cache is not None:
-            cached_len, reused = self.prefix_cache.lookup(req.prompt)
-        if cached_len == 0 and (self.chunk_size is None
-                                or plen <= self.chunk_size):
-            return self._admit_cold(slot, req)
-        return self._start_chunked(slot, req, cached_len, reused)
+        with self._span("serve.admit", uid=req.uid,
+                        prompt_len=plen) as span:
+            cached_len, reused = 0, []
+            if self.prefix_cache is not None:
+                cached_len, reused = self.prefix_cache.lookup(req.prompt)
+            cold = cached_len == 0 and (self.chunk_size is None
+                                        or plen <= self.chunk_size)
+            span.set_metadata(cached_len=cached_len,
+                              path="cold" if cold else "chunked")
+            if cold:
+                return self._admit_cold(slot, req)
+            return self._start_chunked(slot, req, cached_len, reused)
 
     def _admit_cold(self, slot: int, req: Request) -> bool:
         """Whole-prompt admission through the parent's prefill program."""
@@ -575,25 +579,28 @@ class PagedEngine(Engine):
         bucket = self._bucket(plen)
         toks = np.full((1, bucket), self.pad_id, np.int32)
         toks[0, :plen] = req.prompt
-        t0 = time.perf_counter()
-        logits, one = self._prefill(self.params, jnp.asarray(toks),
-                                    jnp.full((1,), plen, jnp.int32))
-        first = self._first_token(logits)
+        with self._span("serve.prefill", "prefill_s", uid=req.uid,
+                        bucket=bucket):
+            logits, one = self._prefill(self.params, jnp.asarray(toks),
+                                        jnp.full((1,), plen, jnp.int32))
+            first = self._first_token(logits)
         live = not ((self.eos_id is not None and first == self.eos_id)
                     or req.max_new_tokens <= 1
                     or plen >= self.max_len)
         if live:
-            blocks = self._allocate(-(-plen // self.block_size))
-            self._seq_blocks[slot] = blocks
-            self._tables[slot, :] = 0
-            self._tables[slot, :len(blocks)] = blocks
-            self._pools = self._scatter_cold(
-                self._pools, one, jnp.asarray(self._tables[slot]),
-                jnp.int32(plen), width=bucket)
-            jax.block_until_ready(self._pools)
-            if self.prefix_cache is not None:
-                self.prefix_cache.insert(req.prompt, blocks)
-        self.stats.prefill_s += time.perf_counter() - t0
+            n = -(-plen // self.block_size)
+            with self._span("serve.scatter", "prefill_s", uid=req.uid,
+                            blocks=n):
+                blocks = self._allocate(n)
+                self._seq_blocks[slot] = blocks
+                self._tables[slot, :] = 0
+                self._tables[slot, :len(blocks)] = blocks
+                self._pools = self._scatter_cold(
+                    self._pools, one, jnp.asarray(self._tables[slot]),
+                    jnp.int32(plen), width=bucket)
+                jax.block_until_ready(self._pools)
+                if self.prefix_cache is not None:
+                    self.prefix_cache.insert(req.prompt, blocks)
         self.stats.prefill_tokens += plen
 
         req.output.append(first)
@@ -660,21 +667,20 @@ class PagedEngine(Engine):
         toks = np.full((1, w), self.pad_id, np.int32)
         real = req.prompt[start:min(start + w, plen)]
         toks[0, :len(real)] = real
-        t0 = time.perf_counter()
-        logits, self._pools = self._paged_extend(
-            self.params, jnp.asarray(toks), jnp.int32(start), self._pools,
-            jnp.asarray(st["row"]), jnp.int32(st["cached"]),
-            jnp.int32(plen))
-        st["next"] += 1
-        if st["next"] < len(st["chunks"]):
+        with self._span("serve.chunk", "prefill_s", uid=req.uid,
+                        start=start, width=w):
+            logits, self._pools = self._paged_extend(
+                self.params, jnp.asarray(toks), jnp.int32(start),
+                self._pools, jnp.asarray(st["row"]), jnp.int32(st["cached"]),
+                jnp.int32(plen))
+            st["next"] += 1
+            last = st["next"] == len(st["chunks"])
+            if last:
+                # the prompt's final real token sits at row plen-1-start
+                first = self._first_token(logits[:, plen - 1 - start])
             jax.block_until_ready(self._pools)
-            self.stats.prefill_s += time.perf_counter() - t0
+        if not last:
             return None
-
-        # last chunk: the prompt's final real token sits at row plen-1-start
-        first = self._first_token(logits[:, plen - 1 - start])
-        jax.block_until_ready(self._pools)
-        self.stats.prefill_s += time.perf_counter() - t0
         self.stats.prefill_tokens += plen
         del self._prefilling[slot]
 
@@ -710,30 +716,34 @@ class PagedEngine(Engine):
                 if r is not None and i not in self._prefilling]
         if not live:
             return finished
-        for i in live:
-            assert self._pos[i] < self.max_len
-            self._ensure_block(i)
-
-        t0 = time.perf_counter()
-        self.key, k = jax.random.split(self.key)
-        nxt, self._pools = self._paged_decode(
-            self.params, jnp.asarray(self._cur), jnp.asarray(self._pos),
-            self._pools, jnp.asarray(self._tables), k)
-        nxt_host = np.asarray(jax.block_until_ready(nxt))
-        self.stats.decode_s += time.perf_counter() - t0
-        self.stats.decode_steps += 1
-
-        for i in live:
-            r = self.slots[i]
-            tok = int(nxt_host[i])
-            r.output.append(tok)
-            self.stats.decode_tokens += 1
-            self._pos[i] += 1
-            self._cur[i] = tok
-            if (self.eos_id is not None and tok == self.eos_id) \
-                    or len(r.output) >= r.max_new_tokens \
-                    or self._pos[i] >= self.max_len:
-                self._finish(r)
-                finished.append(r)
-                self._free(i)
+        with self._span("serve.decode", live=len(live)):
+            for i in live:
+                assert self._pos[i] < self.max_len
+                self._ensure_block(i)
+            with self._span("serve.decode.prep", "decode_s"):
+                self.key, k = jax.random.split(self.key)
+                cur, pos = jnp.asarray(self._cur), jnp.asarray(self._pos)
+                tables = jnp.asarray(self._tables)
+            with self._span("serve.decode.dispatch", "decode_s"):
+                nxt, self._pools = self._paged_decode(
+                    self.params, cur, pos, self._pools, tables, k)
+            with self._span("serve.decode.wait", "decode_s"):
+                nxt_host = np.asarray(jax.block_until_ready(nxt))
+            self.stats.decode_steps += 1
+            with self._span("serve.retire") as span:
+                n = len(finished)
+                for i in live:
+                    r = self.slots[i]
+                    tok = int(nxt_host[i])
+                    r.output.append(tok)
+                    self.stats.decode_tokens += 1
+                    self._pos[i] += 1
+                    self._cur[i] = tok
+                    if (self.eos_id is not None and tok == self.eos_id) \
+                            or len(r.output) >= r.max_new_tokens \
+                            or self._pos[i] >= self.max_len:
+                        self._finish(r)
+                        finished.append(r)
+                        self._free(i)
+                span.set_metadata(finished=len(finished) - n)
         return finished

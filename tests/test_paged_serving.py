@@ -272,3 +272,131 @@ def test_kv_cache_update_debug_bounds_rejects_traced_oob():
             jax.block_until_ready(fn(jnp.array([99], jnp.int32)))
         out = jax.block_until_ready(fn(jnp.array([1], jnp.int32)))
         assert float(out[0, 1, 0]) == 1.0
+
+
+# -- serve.* spans and the engine's one clock ------------------------------
+
+#: prompts that take the cold path (<= chunk_size 16) and the chunked one
+SPAN_REQS = [(5, 4), (20, 3), (9, 1), (12, 5), (33, 2)]
+
+
+def _span_requests(cfg):
+    rng = np.random.RandomState(7)
+    return [(rng.randint(1, cfg.vocab_size, size=n).tolist(), budget)
+            for n, budget in SPAN_REQS]
+
+
+def _serve(cfg, params, reqs):
+    eng = mk_paged(cfg, params, max_batch=2, chunk_size=16)
+    for prompt, budget in reqs:
+        eng.add_request(prompt, max_new_tokens=budget)
+    return eng, {r.uid: r.output for r in eng.run()}
+
+
+def _serve_spans(log_dir):
+    """(name, start_ns, end_ns, args) of every serve.* host event."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("serve.")]
+
+
+@pytest.fixture(scope="module")
+def traced_serve(paged_model, tmp_path_factory):
+    """The span requests served once under a profiler trace."""
+    cfg, params = paged_model
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    log_dir = str(tmp_path_factory.mktemp("serve-trace"))
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    try:
+        eng, out = _serve(cfg, params, _span_requests(cfg))
+    finally:
+        jax.profiler.stop_trace()
+    return eng, out, _serve_spans(log_dir)
+
+
+def _inside(child, parents):
+    return [p for p in parents
+            if p[1] <= child[1] and child[2] <= p[2] and p is not child]
+
+
+def test_serve_spans_nest_and_name_their_requests(traced_serve):
+    eng, out, spans = traced_serve
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    assert set(by) == {"serve.admit", "serve.prefill", "serve.scatter",
+                       "serve.chunk", "serve.decode", "serve.decode.prep",
+                       "serve.decode.dispatch", "serve.decode.wait",
+                       "serve.retire"}
+    # one admission per request, named by its uid, with its path
+    admits = {s[3]["uid"]: s[3] for s in by["serve.admit"]}
+    assert sorted(admits) == sorted(out)
+    for uid, args in admits.items():
+        plen = SPAN_REQS[uid - 1][0]
+        assert args["prompt_len"] == plen and args["cached_len"] == 0
+        assert args["path"] == ("cold" if plen <= 16 else "chunked")
+    # prefill and scatter sit inside the admission of the same request
+    for s in by["serve.prefill"] + by["serve.scatter"]:
+        parent, = _inside(s, by["serve.admit"])
+        assert parent[3]["uid"] == s[3]["uid"]
+    assert {s[3]["uid"] for s in by["serve.chunk"]} == \
+        {uid for uid, a in admits.items() if a["path"] == "chunked"}
+    # a request that ends at admission (budget 1) never scatters
+    assert 3 not in {s[3]["uid"] for s in by["serve.scatter"]}
+    # one serve.decode per decode step; its parts and retirement inside it
+    decodes = by["serve.decode"]
+    assert len(decodes) == eng.stats.decode_steps
+    for name in ("serve.decode.prep", "serve.decode.dispatch",
+                 "serve.decode.wait", "serve.retire"):
+        assert len(by[name]) == len(decodes)
+        for s in by[name]:
+            assert len(_inside(s, decodes)) == 1
+    assert sum(d[3]["live"] for d in decodes) == eng.stats.decode_tokens
+    # every request that decoded finished in some retirement
+    assert sum(s[3]["finished"] for s in by["serve.retire"]) == \
+        eng.stats.decoded_requests
+
+
+def test_tokens_identical_with_profiler_on_and_off(paged_model,
+                                                   traced_serve):
+    cfg, params = paged_model
+    _, traced, _ = traced_serve
+    _, plain = _serve(cfg, params, _span_requests(cfg))
+    assert traced == plain
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_stats_and_timestamps_share_the_engine_clock(paged_model, paged):
+    """With an injected clock that ticks by 1 at every read, each timed
+    region (prefill, scatter, decode prep/dispatch/wait) adds exactly one
+    tick to EngineStats, and Request timestamps are ticks of the same
+    clock: no other clock is read."""
+    cfg, params = paged_model
+    ticks = iter(range(1, 10 ** 6))
+    clock = lambda: float(next(ticks))      # noqa: E731
+    kw = dict(max_batch=2, max_len=64, clock=clock)
+    eng = mk_paged(cfg, params, **kw) if paged else Engine(cfg, params, **kw)
+    budgets = (3, 1, 4, 2)
+    rng = np.random.RandomState(8)
+    for budget in budgets:
+        eng.add_request(rng.randint(1, cfg.vocab_size, size=7).tolist(),
+                        max_new_tokens=budget)
+    done = eng.run()
+    s = eng.stats
+    live = sum(b > 1 for b in budgets)      # budget 1 never scatters
+    assert s.prefill_s == len(budgets) + live
+    assert s.decode_s == 3 * s.decode_steps > 0
+    last = next(ticks)
+    for r in done:
+        stamps = (r.enqueue_t, r.admit_t, r.first_token_t, r.finish_t)
+        assert all(t == int(t) for t in stamps)
+        assert 0 < r.enqueue_t < r.admit_t < r.first_token_t \
+            <= r.finish_t < last
