@@ -127,6 +127,8 @@ def serve(cfg, params, prompts, backend: str, mesh=None, seed: int = 0):
     with nn.backend(backend):
         eng = PagedEngine(cfg, params, max_batch=MAX_BATCH, max_len=MAX_LEN,
                           fused=True, mesh=mesh, seed=seed)
+        log(f"engine tp={eng.tp}: decode reaches the KV cache by the "
+            f"{eng.kv_path} path")
         bucket = eng._bucket(PROMPT_HI)
         width = eng._chunk_plan(SHARED, len(prompts[3]))[0][1]
         row = jnp.zeros((eng.blocks_per_seq,), jnp.int32)

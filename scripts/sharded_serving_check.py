@@ -14,7 +14,8 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # must agree exactly, which is the property serving cares about.
 #
 # Usage: python scripts/sharded_serving_check.py \
-#            <parity_decode|parity_chunked|parity_prefix|bench>
+#            <parity_decode|parity_paged_kernel|parity_chunked|
+#             parity_prefix|bench>
 
 import json
 import sys
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import nn
 from repro.bench.cases import sharded_serving_config
 from repro.core import get_hardware, model_records
 from repro.core.graph import capture
@@ -86,6 +88,25 @@ def main(mode: str) -> int:
         assert_parity(2)
         assert_parity(8)
         print("parity_decode OK")
+        return 0
+
+    if mode == "parity_paged_kernel":
+        # fused TP engines read the head-sharded pools in place through
+        # the paged decode kernel (Pallas interpret mode); their streams
+        # must equal the single-device gathered path's (fused=False)
+        plist = prompts(6, 4, 20)
+        with nn.backend("pallas_interpret"):
+            base = make_engine(1)
+            ref = serve(base, plist)
+            assert base.kv_path == "gathered", base.kv_path
+            for tp in (2, 4):
+                eng = make_engine(tp, fused=True)
+                assert eng.kv_path == "paged", (tp, eng.kv_path)
+                out = serve(eng, plist)
+                assert out == ref, (
+                    f"tp={tp} paged-kernel streams diverge from the "
+                    f"gathered path:\n  gathered: {ref}\n  paged:    {out}")
+        print("parity_paged_kernel OK")
         return 0
 
     if mode == "parity_chunked":

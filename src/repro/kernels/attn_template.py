@@ -15,7 +15,11 @@ generates each variant from an :class:`AttnSpec`:
   ``full`` / ``decode`` (per-row valid-length via scalar prefetch);
 * ``v_head_dim`` may differ from ``head_dim`` (MLA: latent values), and
   GQA is an index-map fragment (KV block row ``(h % hq) // g`` — no HBM
-  replication).
+  replication);
+* ``paged=True`` on the decode mask reads K/V in place from the paged
+  block pool: its index maps read the block tables by scalar prefetch,
+  several pool blocks per grid step, and a KV head's query heads share
+  one tile (:func:`paged_decode_core`).
 
 The epilogue guards fully-masked query rows: a row whose every key is
 masked carries ``m == NEG_INF`` out of the loop (NEG_INF is finite, so the
@@ -74,11 +78,15 @@ class AttnSpec:
     scale: Optional[float] = None        # None -> 1/sqrt(dk)
     block_q: int = 128
     block_k: int = 128
+    paged: bool = False                  # decode: K/V from the block pool
 
     def __post_init__(self):
         if self.mask not in MASK_KINDS:
             raise ValueError(f"spec {self.name!r}: unknown mask kind "
                              f"{self.mask!r}; known: {MASK_KINDS}")
+        if self.paged and self.mask != "decode":
+            raise ValueError(f"spec {self.name!r}: only the decode mask "
+                             "reads a paged pool")
         if self.mask == "window" and self.window is not None \
                 and self.window <= 0:
             raise ValueError(f"spec {self.name!r}: window must be positive")
@@ -343,6 +351,167 @@ def decode_core(q, k, v, lengths, *, scale: Optional[float] = None,
 
 
 # ---------------------------------------------------------------------------
+# paged decode-1q: K/V read in place from the block pool
+# ---------------------------------------------------------------------------
+
+#: widest score row one grid step computes: pages_per_step * bs * Hkv
+#: lanes (the (Hq, W) f32 scores stay at 128 vregs for Hq = 32)
+PAGED_ROW_LANES = 4096
+
+
+def _paged_block(b, w, p, lengths_ref, tables_ref, *, nb: int, bs: int,
+                 pages: int):
+    """Pool block that page input ``p`` holds at grid step ``(b, w)``.
+
+    Input ``p`` reads table entry ``w * pages + p`` while that block is
+    live (below ``ceil(length / bs)``). Past it, the input keeps the entry
+    of the last window in which it was live, so the pipeline sees the same
+    block index again and issues no copy; an input with no live block in
+    this sequence points at the scratch block 0. Either way the rows it
+    holds are finite and lie past the length, where the mask zeroes them.
+    """
+    live = (lengths_ref[b] + bs - 1) // bs
+    last_w = jnp.maximum((live - 1 - p) // pages, 0)
+    j = jnp.minimum(jnp.minimum(w, last_w) * pages + p, nb - 1)
+    return jnp.where(p < live, tables_ref[b * nb + j], 0)
+
+
+def _paged_decode_kernel(lengths_ref, tables_ref, layer_ref, q_ref,
+                         col_head_ref, col_tok_ref, row_head_ref, *refs,
+                         scale: float, softcap: Optional[float], pages: int,
+                         span: int):
+    """decode-1q over one window of ``pages`` pool blocks of sequence b.
+
+    Grid ``(B, windows)``; each page input is one pool block
+    ``(bs * Hkv, D)``, whose row ``t * Hkv + h`` is token ``t``'s KV head
+    ``h`` (the pool's own order, so the block arrives by one copy). The
+    scores of ALL query heads against every row of the window are one MXU
+    product per block, ``q @ K^T``, laid side by side as ``(Hq, W)``; the
+    mask keeps, per query head, the rows of its own KV head below the
+    sequence's length. A GQA group therefore reads each K/V row once.
+    Windows past the length compute nothing.
+    """
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    b, w = pl.program_id(0), pl.program_id(1)
+    length = lengths_ref[b]
+
+    @pl.when(w == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(w * span < length)
+    def _window():
+        q = q_ref[0]                                    # (Hq, Dk)
+        s = jnp.concatenate([
+            jax.lax.dot_general(q, k[0, 0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for k in k_refs], axis=1) * scale           # (Hq, W)
+        if softcap is not None:
+            s = jnp.tanh(s / softcap) * softcap
+        mask = (col_head_ref[...] == row_head_ref[...]) \
+            & (w * span + col_tok_ref[...] < length)
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        rows = s.shape[1] // pages
+        pv = acc_ref[...] * corr
+        for i, v in enumerate(v_refs):
+            pv += jax.lax.dot_general(
+                p[:, i * rows:(i + 1) * rows].astype(v.dtype), v[0, 0],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        acc_ref[...] = pv
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _finish():
+        # a dead sequence (length 0) saw no key: exact zeros, as in the
+        # oracle
+        l = l_ref[...]
+        o_ref[0] = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30),
+                             0.0).astype(o_ref.dtype)
+
+
+def paged_decode_core(q, k_pool, v_pool, tables, lengths, layer=None, *,
+                      scale: Optional[float] = None,
+                      softcap: Optional[float] = None,
+                      pages_per_step: Optional[int] = None,
+                      interpret: bool = False):
+    """The paged decode-1q entry point: K/V read in place from the pool.
+
+    q: (B, 1, Hq, D); k_pool / v_pool: one layer's pool leaves
+    (N, bs, Hkv, D), or the layer-stacked (L, N, bs, Hkv, D) with
+    ``layer`` (int32 scalar) naming the layer; tables: (B, nb) int32 block
+    ids; lengths: (B,) int32 valid prefix per row -> (B, 1, Hq, D).
+
+    ``lengths``, ``tables`` and ``layer`` arrive by scalar prefetch, and
+    the K/V index maps read the tables, so the pipeline copies pool blocks
+    in place. A grid step takes ``pages_per_step`` blocks, one input each,
+    so that each step moves enough bytes to pay for itself (default: as
+    many as keep the score row at :data:`PAGED_ROW_LANES` lanes, at most a
+    whole table row). Blocks at or past ``ceil(lengths[b] / bs)`` are
+    neither copied nor computed.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    if k_pool.ndim == 4:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    nl, n, bs, hkv, dk = k_pool.shape
+    dv = v_pool.shape[-1]
+    b, _, hq, _ = q.shape
+    nb = tables.shape[1]
+    g = hq // hkv
+    rows = bs * hkv
+    pages = pages_per_step or max(1, min(nb, PAGED_ROW_LANES // rows))
+    windows = -(-nb // pages)
+    scale = scale if scale is not None else 1.0 / math.sqrt(dk)
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(b)
+    col = jnp.arange(pages * rows, dtype=jnp.int32)[None, :]
+    row_head = (jnp.arange(hq, dtype=jnp.int32) // g)[:, None]
+
+    def const(shape):
+        return pl.BlockSpec(shape, lambda i, w, *_: (0,) * len(shape))
+
+    def page_spec(p, d):
+        def index(i, w, lens, tabs, lay):
+            return (lay[0], _paged_block(i, w, p, lens, tabs, nb=nb, bs=bs,
+                                         pages=pages), 0, 0)
+        return pl.BlockSpec((1, 1, rows, d), index)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, windows),
+        in_specs=[pl.BlockSpec((1, hq, dk), lambda i, w, *_: (i, 0, 0)),
+                  const((1, pages * rows)), const((1, pages * rows)),
+                  const((hq, 1))]
+        + [page_spec(p, dk) for p in range(pages)]
+        + [page_spec(p, dv) for p in range(pages)],
+        out_specs=pl.BlockSpec((1, hq, dv), lambda i, w, *_: (i, 0, 0)),
+        scratch_shapes=[_vmem((hq, 1)), _vmem((hq, 1)), _vmem((hq, dv))],
+    )
+    kf = k_pool.reshape(nl, n, rows, dk)
+    vf = v_pool.reshape(nl, n, rows, dv)
+    out = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, scale=scale,
+                          softcap=softcap, pages=pages, span=pages * bs),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, dv), v_pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(lengths, tables.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(b, hq, dk).astype(k_pool.dtype), col % hkv, col // hkv,
+      row_head, *([kf] * pages), *([vf] * pages))
+    return out.reshape(b, 1, hq, dv)
+
+
+# ---------------------------------------------------------------------------
 # the generator: spec -> raw kernel entry point (+ auto-registration)
 # ---------------------------------------------------------------------------
 
@@ -360,12 +529,25 @@ def build_raw(spec: AttnSpec) -> Tuple[Callable, Tuple[str, ...]]:
             raise ValueError(f"{spec.name}: v_head_dim {v.shape[-1]} != "
                              f"pinned {spec.v_head_dim}")
         if spec.gqa_group is not None \
-                and q.shape[2] != k.shape[2] * spec.gqa_group:
+                and q.shape[2] != k.shape[-2] * spec.gqa_group:
             raise ValueError(f"{spec.name}: GQA group "
-                             f"{q.shape[2]}/{k.shape[2]} != pinned "
+                             f"{q.shape[2]}/{k.shape[-2]} != pinned "
                              f"{spec.gqa_group}")
 
-    if spec.mask == "decode":
+    if spec.paged:
+        def fn(q, k_pool, v_pool, tables, lengths, layer=None, *,
+               scale: Optional[float] = None,
+               softcap: Optional[float] = None,
+               pages_per_step: Optional[int] = None,
+               interpret: bool = False):
+            _check(q, k_pool, v_pool)
+            return paged_decode_core(
+                q, k_pool, v_pool, tables, lengths, layer,
+                scale=spec.scale if scale is None else scale,
+                softcap=spec.softcap if softcap is None else softcap,
+                pages_per_step=pages_per_step, interpret=interpret)
+        static = ("scale", "softcap", "pages_per_step", "interpret")
+    elif spec.mask == "decode":
         def fn(q, k, v, lengths, *, scale: Optional[float] = None,
                softcap: Optional[float] = None,
                block_q: int = spec.block_q, block_k: int = spec.block_k,
@@ -431,6 +613,7 @@ BUILTIN_SPECS: Tuple[AttnSpec, ...] = (
     AttnSpec(name="window", mask="window"),
     AttnSpec(name="full", mask="full"),
     AttnSpec(name="decode", mask="decode", block_q=8),
+    AttnSpec(name="paged_decode", mask="decode", paged=True),
 )
 
 

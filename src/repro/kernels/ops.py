@@ -152,5 +152,8 @@ del _s
 #: the decode-1q template variant — the fused decode kernel the engine
 #: and the ``fused_attn_decode`` fusion pattern route through
 attn_decode_template = _tmpl.get("decode")
+#: its paged twin: K/V read in place from the block pool through the
+#: block tables (``PagedEngine``'s decode on a kernel backend)
+attn_decode_paged_template = _tmpl.get("paged_decode")
 #: the full/cross variant (vision encoder, detector query refinement)
 attn_full_template = _tmpl.get("full")

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -582,6 +582,59 @@ def attn_decode(params, x, cfg: ModelConfig, kind: str, cache: dict,
                        preferred_element_type=jnp.float32)
     o = o.reshape(b, 1, hq * hd).astype(x.dtype)
     return nn.linear(o, params["wo"].astype(x.dtype)), new_cache
+
+
+def fit_head(x, width: int):
+    """``x`` with its last (head) dim zero-padded or cut to ``width``.
+
+    A paged block pool may keep its head dim padded past the model's
+    (``serving.paged.init_pools``): rows are padded on their way into it
+    and views cut on their way out. Zero lanes add nothing to ``q . k``
+    and yield zero output lanes.
+    """
+    d = x.shape[-1]
+    if d < width:
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - d)])
+    return x[..., :width] if d > width else x
+
+
+class PagedKV(NamedTuple):
+    """One attention layer's view of the paged block pool during decode:
+    the pool leaves ``k`` / ``v`` (N, bs, Hkv, Dp), or the layer-stacked
+    (L, N, bs, Hkv, Dp) with ``layer`` naming this layer, and the batch's
+    block ``tables`` (B, nb). The pool's head dim Dp may be padded past
+    the model's (:func:`fit_head`)."""
+
+    k: jax.Array
+    v: jax.Array
+    tables: jax.Array
+    layer: Optional[jax.Array] = None
+
+
+def attn_decode_paged(params, x, cfg: ModelConfig, cache: PagedKV,
+                      pos) -> Tuple[jax.Array, dict]:
+    """One-token decode straight from the paged block pool.
+
+    The new K/V row of each sequence goes into its block in place
+    (``nn.paged_kv_write``); the fused decode operator then reads the live
+    blocks through the tables. q and the rows are padded to the pool's
+    head dim and the output cut back (:func:`fit_head`). Returns
+    (y, {"k", "v"}: the updated pool leaves). No contiguous view of the
+    cache is made.
+    """
+    b = x.shape[0]
+    hq, hd = cfg.n_heads, cfg.resolved_head_dim
+    dp = cache.k.shape[-1]
+    pos = pos_vector(pos, b)
+    q, k_new, v_new = (fit_head(t, dp)
+                       for t in _qkv(params, x, cfg, pos[:, None]))
+    k = nn.paged_kv_write(cache.k, k_new, cache.tables, pos, cache.layer)
+    v = nn.paged_kv_write(cache.v, v_new, cache.tables, pos, cache.layer)
+    o = nn.fused_attn_decode(q, k, v, pos + 1, scale=1.0 / math.sqrt(hd),
+                             softcap=cfg.attn_logit_softcap,
+                             tables=cache.tables, layer=cache.layer)
+    o = fit_head(o, hd).reshape(b, 1, hq * hd).astype(x.dtype)
+    return nn.linear(o, params["wo"].astype(x.dtype)), {"k": k, "v": v}
 
 
 def attn_extend(params, x, cfg: ModelConfig, kind: str, cache: dict,

@@ -249,6 +249,8 @@ def block_decode(params, x, cfg: ModelConfig, kind: str, cache, pos,
         a, cache = X.mlstm_decode(params["mixer"], h, cfg, cache, pos)
     elif kind == "slstm":
         a, cache = X.slstm_decode(params["mixer"], h, cfg, cache, pos)
+    elif isinstance(cache, A.PagedKV):
+        a, cache = A.attn_decode_paged(params["mixer"], h, cfg, cache, pos)
     elif cfg.mla:
         a, cache = A.mla_decode(params["mixer"], h, cfg, cache, pos)
     else:
@@ -564,12 +566,18 @@ def lm_prefill(params, inputs, cfg: ModelConfig, max_len: int,
     return logits, caches
 
 
-def lm_decode(params, token, pos, caches, cfg: ModelConfig):
+def lm_decode(params, token, pos, caches, cfg: ModelConfig, tables=None):
     """One decode step.
 
     token: (B,) int32 (or (B, D) frame embedding for input_mode=embeddings);
     pos: scalar int32 (lockstep batch) or (B,) int32 per-slot absolute
     positions (continuous batching). Returns (logits (B, V), new_caches).
+
+    With ``tables`` (B, nb) int32, ``caches`` are paged block pools of
+    plain attention layers (``serving.paged.init_pools``): the layer scan
+    carries the stacked pools instead of slicing them, and each layer
+    writes its new rows and reads its live blocks in place
+    (``attention.attn_decode_paged``).
     """
     lead, pattern, n_rep, trail = _layer_layout(cfg)
     lead_f, pat_f, trail_f = _moe_flags(cfg)
@@ -579,12 +587,31 @@ def lm_decode(params, token, pos, caches, cfg: ModelConfig):
     inputs = token[:, None] if cfg.input_mode == "tokens" else token[:, None, :]
     x = embed_inputs(params, inputs, cfg, positions)
 
+    def view(c, layer=None):
+        if tables is None:
+            return c
+        return A.PagedKV(c["k"], c["v"], tables, layer)
+
     new_caches = {"lead": [], "scan": [], "trail": []}
     for p, kind, mf, c in zip(params["lead"], lead, lead_f, caches["lead"]):
-        x, c = block_decode(p, x, cfg, kind, c, pos, mf)
+        x, c = block_decode(p, x, cfg, kind, view(c), pos, mf)
         new_caches["lead"].append(c)
 
-    if n_rep:
+    if n_rep and tables is not None:
+        def paged_body(carry, ps):
+            x, cs, layer = carry
+            new_cs = []
+            for j, kind in enumerate(pattern):
+                x, c = block_decode(ps[j], x, cfg, kind, view(cs[j], layer),
+                                    pos, pat_f[j])
+                new_cs.append(c)
+            return (x, tuple(new_cs), layer + 1), None
+
+        (x, scan_caches, _), _ = jax.lax.scan(
+            paged_body, (x, tuple(caches["scan"]), jnp.int32(0)),
+            tuple(params["scan"]))
+        new_caches["scan"] = list(scan_caches)
+    elif n_rep:
         def body(x, sliced):
             ps, cs = sliced
             new_cs = []
@@ -599,7 +626,7 @@ def lm_decode(params, token, pos, caches, cfg: ModelConfig):
 
     for p, kind, mf, c in zip(params["trail"], trail, trail_f,
                               caches["trail"]):
-        x, c = block_decode(p, x, cfg, kind, c, pos, mf)
+        x, c = block_decode(p, x, cfg, kind, view(c), pos, mf)
         new_caches["trail"].append(c)
 
     h = _apply_norm(params["final_norm"], x, cfg)

@@ -398,19 +398,24 @@ def paged_kv_gather(pool, block_table, max_len: int):
 
 
 @tagged(OpGroup.MEMORY, "paged_kv_write")
-def paged_kv_write(pool, new, block_table, index):
+def paged_kv_write(pool, new, block_table, index, layer=None):
     """Scatter one decode row per sequence into its paged block.
 
     ``new`` is (B, 1, ...); ``index`` (B,) is each sequence's position.
     Row ``b`` lands in pool block ``block_table[b, index[b] // bs]`` at
     offset ``index[b] % bs``. Sequences whose table slot is 0 write the
-    reserved scratch block (dead/prefilling slots stay harmless).
+    reserved scratch block (dead/prefilling slots stay harmless). With
+    ``layer`` (int32 scalar) the pool is layer-stacked (L, N, bs, ...) and
+    only that layer's rows are written, in place.
     """
-    bs = pool.shape[1]
+    bs = pool.shape[1 if layer is None else 2]
     index = jnp.asarray(index, jnp.int32)
     block_ids = jnp.take_along_axis(
         block_table, (index // bs)[:, None], axis=1)[:, 0]
-    return pool.at[block_ids, index % bs].set(new[:, 0].astype(pool.dtype))
+    rows = new[:, 0].astype(pool.dtype)
+    if layer is None:
+        return pool.at[block_ids, index % bs].set(rows)
+    return pool.at[layer, block_ids, index % bs].set(rows)
 
 
 @tagged(OpGroup.MEMORY, "paged_kv_scatter")
@@ -643,7 +648,8 @@ def _fused_qdq(x):
 
 @tagged(OpGroup.FUSED, "fused_attn_decode")
 def fused_attn_decode(q, k, v, lengths, scale: Optional[float] = None,
-                      softcap: Optional[float] = None):
+                      softcap: Optional[float] = None, tables=None,
+                      layer=None):
     """One-query decode attention over a per-row valid KV prefix as ONE
     operator — the ``attn_template:decode`` variant on the kernel backends.
 
@@ -655,7 +661,21 @@ def fused_attn_decode(q, k, v, lengths, scale: Optional[float] = None,
     rows between each — the chain ``FUSION_PATTERNS`` rewrites to this
     record. The jnp fallback mirrors the unfused op sequence exactly
     (bit-identical tokens); the Pallas variant agrees to float tolerance.
+
+    With ``tables`` (B, nb) the K/V operands are paged block pools
+    (N, bs, Hkv, D), or layer-stacked (L, N, bs, Hkv, D) with ``layer``:
+    the ``attn_template:paged_decode`` variant reads the live blocks in
+    place, so no contiguous view of the cache is ever made. That form has
+    no jnp fallback; the engine gathers a view on the jnp backend
+    (``serving.paged.decode_kv_path``).
     """
+    if tables is not None:
+        if _BACKEND == "jnp":
+            raise ValueError("paged decode attention needs a kernel "
+                             "backend; the jnp backend gathers a view")
+        return _kernels().attn_decode_paged_template(
+            q, k, v, tables, lengths, layer, scale=scale, softcap=softcap,
+            interpret=kernel_interpret())
     if _BACKEND != "jnp":
         return _kernels().attn_decode_template(
             q, k, v, lengths, scale=scale, softcap=softcap,

@@ -8,13 +8,20 @@ the vLLM paging scheme, append-only so no copy-on-write is ever needed.
 
 Three mechanisms ride on the block tables:
 
-* **paged decode** — every step gathers each sequence's blocks into a
-  contiguous ``(B, max_len, ...)`` view (``nn.paged_kv_gather``), runs the
-  UNCHANGED ``lm_decode`` program on it, then scatters the one new KV row
-  per sequence back into its block (``nn.paged_kv_write``). Stale rows in
-  the view are hidden by decode's per-row ``arange <= pos`` mask, whose
-  masked terms are exact zeros — which is what makes paged decode
-  bit-identical to the contiguous engine.
+* **paged decode** — on a kernel backend with ``fused=True`` and plain
+  attention layers, the decode program reads the pool in place: the
+  stacked pools ride through the layer scan as its carry, and each layer
+  writes its sequences' new K/V rows into their blocks
+  (``nn.paged_kv_write``) and runs the ``attn_template:paged_decode``
+  kernel, whose index maps walk the block tables and copy only live
+  blocks. No contiguous view is made. Elsewhere (MLA, TP with replicated
+  pools, ``fused=False``, the ``jnp`` backend) every step gathers each
+  sequence's blocks into a contiguous ``(B, max_len, ...)`` view
+  (``nn.paged_kv_gather``), runs the UNCHANGED ``lm_decode`` program on
+  it, then writes the one new KV row per sequence back into its block.
+  Stale rows in the view are hidden by decode's per-row ``arange <= pos``
+  mask, whose masked terms are exact zeros — which is what makes that
+  path bit-identical to the contiguous engine.
 * **prefix cache** — full prompt blocks are registered in a hash-chain
   keyed :class:`PrefixCache` at admission; later prompts sharing the
   prefix re-point their table at the cached blocks and prefill only the
@@ -43,6 +50,7 @@ from jax.sharding import PartitionSpec as P
 from repro import nn, sharding
 from repro.models import init_lm_cache, lm_decode, lm_extend, lm_prefill
 from repro.models import tp as tp_mod
+from repro.models.attention import fit_head
 from repro.models.common import ModelConfig
 from repro.runtime import cast_params
 from repro.serving import Engine, Request, _next_pow2
@@ -182,13 +190,61 @@ class PrefixCache:
 
 
 # ---------------------------------------------------------------------------
-# jitted paged programs (gather view -> unchanged model program -> scatter)
+# jitted paged programs
 # ---------------------------------------------------------------------------
 
-def _gather_tree(pools: dict, tables, max_len: int) -> dict:
-    """Materialize the contiguous (B, max_len, ...) cache view per leaf."""
+def decode_kv_path(cfg: ModelConfig, fused: bool, tp: int = 1) -> str:
+    """How paged decode reaches the KV cache: ``"paged"`` (the kernel reads
+    the pool in place) or ``"gathered"`` (a contiguous view per step).
+
+    Decided from what the step program can see: the paged kernel needs
+    the fused path on a kernel backend, plain (non-MLA) attention on every
+    layer, and a local pool that holds the local config's KV heads (one
+    chip, or TP that divides ``n_kv_heads``).
+    """
+    kernel = fused and nn.get_backend() != "jnp"
+    plain = set(cfg.layer_kinds()) == {"attn"} and not cfg.mla
+    heads = tp <= 1 or tp_mod.tp_kv_sharded(cfg, tp)
+    return "paged" if kernel and plain and heads else "gathered"
+
+
+#: lane width of the device's vector tiles
+LANES = 128
+
+
+def init_pools(cfg: ModelConfig, num_blocks: int, block_size: int,
+               kv: str = "gathered") -> dict:
+    """The block pools: ``init_lm_cache(cfg, num_blocks, block_size)``; for
+    the ``"paged"`` decode path (:func:`decode_kv_path`) each leaf's last
+    (head) dim is zero-padded to a multiple of :data:`LANES`.
+
+    The device's default layout keeps a leaf row-major only where its last
+    dim fills whole lane tiles; for an 80-wide head it puts the block dim
+    innermost instead (least padding), which strides every block through
+    the whole pool, and a program that reads blocks in place converts the
+    whole pool first. A layout forced per program does not survive the
+    persistent compile cache (a program loaded from it expects the default
+    layout), so the pool carries the padding itself. Rows are padded on
+    their way in and gathered views cut on their way out
+    (``attention.fit_head``); on the gathered path the pools keep the
+    model's widths and its programs are unchanged.
+    """
+    if kv != "paged":
+        return init_lm_cache(cfg, num_blocks, block_size)
+    width = -(-cfg.resolved_head_dim // LANES) * LANES
+    struct = jax.eval_shape(lambda: init_lm_cache(cfg, num_blocks,
+                                                  block_size))
+    return jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape[:-1] + (width,), s.dtype), struct)
+
+
+def _gather_tree(pools: dict, tables, max_len: int,
+                 head_dim: Optional[int] = None) -> dict:
+    """Materialize the contiguous (B, max_len, ...) cache view per leaf;
+    with ``head_dim`` (lane-padded pools) each view is cut back to it."""
     def g0(p):
-        return nn.paged_kv_gather(p, tables, max_len)
+        view = nn.paged_kv_gather(p, tables, max_len)
+        return view if head_dim is None else fit_head(view, head_dim)
 
     def g1(p):                      # scan leaves carry a leading layer dim
         return jax.vmap(g0)(p)
@@ -199,6 +255,14 @@ def _gather_tree(pools: dict, tables, max_len: int) -> dict:
         "scan": [tm(g1, c) for c in pools["scan"]],
         "trail": [tm(g0, c) for c in pools["trail"]],
     }
+
+
+def _view_head_dim(cfg: ModelConfig, fused: bool, tp: int) -> Optional[int]:
+    """The model's head dim where the engine's pools are lane-padded (the
+    paged decode path, :func:`init_pools`), else None."""
+    if decode_kv_path(cfg, fused, tp) == "paged":
+        return cfg.resolved_head_dim
+    return None
 
 
 def _writeback_tree(pools: dict, caches: dict, tables, pos) -> dict:
@@ -229,7 +293,8 @@ def _scatter_tree(pools: dict, caches: dict, table_row, start, lo, hi,
     one sequence's blocks (outside [lo, hi) diverts to the scratch block)."""
     def s0(pool, cache):
         rows = jax.lax.dynamic_slice_in_dim(cache[0], start, width, axis=0)
-        return nn.paged_kv_scatter(pool, rows, table_row, start, lo, hi)
+        return nn.paged_kv_scatter(pool, fit_head(rows, pool.shape[-1]),
+                                   table_row, start, lo, hi)
 
     def s1(pool, cache):
         return jax.vmap(s0)(pool, cache)
@@ -261,30 +326,43 @@ def _tp_cache_struct_specs(cfg: ModelConfig, max_len: int, tp: int):
     return tp_mod.tp_cache_specs(struct, cfg, tp)
 
 
+def _decode_body(cfg: ModelConfig, max_len: int, greedy: bool, kv: str):
+    """(working params, token, pos, pools, tables, key) -> (token', pools')
+    under ``cfg`` (the local config inside a TP shard_map)."""
+    def body(working, token, pos, pools, tables, key):
+        if kv == "paged":
+            logits, pools = lm_decode(working, token, pos, pools, cfg,
+                                      tables=tables)
+        else:
+            caches = _gather_tree(pools, tables, max_len)
+            logits, caches = lm_decode(working, token, pos, caches, cfg)
+            pools = _writeback_tree(pools, caches, tables, pos)
+        lf = logits.astype(jnp.float32)
+        if greedy:
+            nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+        else:
+            nxt = jax.random.categorical(key, lf, axis=-1).astype(jnp.int32)
+        return nxt, pools
+    return body
+
+
 def _make_tp_paged_decode_step(cfg: ModelConfig, max_len: int, mesh,
                                tp: int, greedy: bool,
-                               fused: bool) -> Callable:
+                               fused: bool, kv: str) -> Callable:
     """shard_map variant of ``make_paged_decode_step``: every device runs
-    the unchanged paged-decode body on its parameter/pool shards under the
+    the paged-decode body on its parameter/pool shards under the
     per-device config, and the per-block ``nn.tp_psum`` reductions (plus
     the ``nn.tp_vocab_gather`` on a sharded unembedding) become explicit
     COLLECTIVE primitives in the traced jaxpr."""
     local = tp_mod.tp_local_config(cfg, tp)
     vocab = tp_mod.tp_vocab_sharded(cfg, tp)
+    step = _decode_body(local, max_len, greedy, kv)
 
     def body(params, token, pos, pools, tables, key):
         with sharding.manual_axis("model", vocab_sharded=vocab), \
                 nn.fuse(fused):
             working = cast_params(params, local.activation_dtype)
-            caches = _gather_tree(pools, tables, max_len)
-            logits, caches = lm_decode(working, token, pos, caches, local)
-            pools = _writeback_tree(pools, caches, tables, pos)
-            lf = logits.astype(jnp.float32)
-            if greedy:
-                nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
-            else:
-                nxt = jax.random.categorical(key, lf, axis=-1).astype(jnp.int32)
-        return nxt, pools
+            return step(working, token, pos, pools, tables, key)
 
     rep = P()
 
@@ -304,12 +382,14 @@ def _make_tp_paged_extend_step(cfg: ModelConfig, max_len: int, mesh,
     """shard_map variant of ``make_paged_extend_step`` (chunked prefill)."""
     local = tp_mod.tp_local_config(cfg, tp)
     vocab = tp_mod.tp_vocab_sharded(cfg, tp)
+    head_dim = _view_head_dim(cfg, fused, tp)
 
     def body(params, tokens, start, pools, table_row, lo, hi):
         with sharding.manual_axis("model", vocab_sharded=vocab), \
                 nn.fuse(fused):
             working = cast_params(params, local.activation_dtype)
-            caches = _gather_tree(pools, table_row[None, :], max_len)
+            caches = _gather_tree(pools, table_row[None, :], max_len,
+                                  head_dim)
             logits, caches = lm_extend(working, tokens, start, caches, local)
             pools = _scatter_tree(pools, caches, table_row, start, lo, hi,
                                   tokens.shape[1])
@@ -366,40 +446,41 @@ def make_paged_decode_step(cfg: ModelConfig, max_len: int, mesh=None,
                            fused: bool = False) -> Callable:
     """paged_step(params, token, pos, pools, tables, key) -> (token', pools').
 
-    Gathers the block tables into a contiguous view, runs the UNCHANGED
-    ``lm_decode`` program (same sampling tail as ``make_serve_step``), and
-    scatters each sequence's new KV row back into its block.
+    One decode step over the block pools, with the same sampling tail as
+    ``make_serve_step``. The path is fixed here, by
+    :func:`decode_kv_path` under the backend in force now, and recorded as
+    the returned function's ``kv`` attribute:
 
-    With ``fused=True`` the whole body runs under ``nn.fuse()``, which
-    routes every layer's attention over the gathered paged KV through the
-    ``attn_template:decode`` spec (one fused qk->mask->softmax->pv
-    operator per layer, ``fused_attn_decode``) — the per-row ``pos + 1``
-    valid-prefix lengths are exactly the decode-1q template's scalar-
-    prefetch mask, so paged gather + template kernel compose without any
-    paged-specific attention code.
+    * ``"paged"`` — ``lm_decode(..., tables=tables)``: the layer scan
+      carries the pools; each layer writes its sequences' new K/V rows
+      into their blocks in place and runs the fused decode operator on the
+      pool (the ``attn_template:paged_decode`` kernel reads the live
+      blocks through the tables). The only bytes touched per layer are
+      the new rows and the live blocks.
+    * ``"gathered"`` — gathers the block tables into a contiguous view,
+      runs the UNCHANGED ``lm_decode`` program on it, and writes each
+      sequence's new KV row back into its block; under ``nn.fuse()`` the
+      attention over the view is the ``attn_template:decode`` kernel on a
+      kernel backend.
 
     A mesh whose ``model`` axis is larger than 1 selects the manual-TP
     shard_map path (see ``repro.models.tp``): bit-identical token streams,
     explicit COLLECTIVE primitives in the captured program.
     """
     tp = tp_mod.mesh_tp(mesh)
+    kv = decode_kv_path(cfg, fused, tp)
     if tp > 1:
-        return _make_tp_paged_decode_step(cfg, max_len, mesh, tp,
-                                          greedy, fused)
+        paged_step = _make_tp_paged_decode_step(cfg, max_len, mesh, tp,
+                                                greedy, fused, kv)
+    else:
+        step = _decode_body(cfg, max_len, greedy, kv)
 
-    def paged_step(params, token, pos, pools, tables, key):
-        with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
-                nn.fuse(fused):
-            working = cast_params(params, cfg.activation_dtype)
-            caches = _gather_tree(pools, tables, max_len)
-            logits, caches = lm_decode(working, token, pos, caches, cfg)
-            pools = _writeback_tree(pools, caches, tables, pos)
-            lf = logits.astype(jnp.float32)
-            if greedy:
-                nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
-            else:
-                nxt = jax.random.categorical(key, lf, axis=-1).astype(jnp.int32)
-        return nxt, pools
+        def paged_step(params, token, pos, pools, tables, key):
+            with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
+                    nn.fuse(fused):
+                working = cast_params(params, cfg.activation_dtype)
+                return step(working, token, pos, pools, tables, key)
+    paged_step.kv = kv
     return paged_step
 
 
@@ -419,12 +500,14 @@ def make_paged_extend_step(cfg: ModelConfig, max_len: int, mesh=None,
     tp = tp_mod.mesh_tp(mesh)
     if tp > 1:
         return _make_tp_paged_extend_step(cfg, max_len, mesh, tp, fused)
+    head_dim = _view_head_dim(cfg, fused, tp)
 
     def extend_step(params, tokens, start, pools, table_row, lo, hi):
         with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
                 nn.fuse(fused):
             working = cast_params(params, cfg.activation_dtype)
-            caches = _gather_tree(pools, table_row[None, :], max_len)
+            caches = _gather_tree(pools, table_row[None, :], max_len,
+                                  head_dim)
             logits, caches = lm_extend(working, tokens, start, caches, cfg)
             pools = _scatter_tree(pools, caches, table_row, start, lo, hi,
                                   tokens.shape[1])
@@ -487,7 +570,12 @@ class PagedEngine(Engine):
         # the parent's contiguous shared cache is never used (its decode
         # and insert jits stay untraced — jax.jit is lazy)
         self._caches = None
-        self._pools = init_lm_cache(cfg, num_blocks, block_size)
+        decode = make_paged_decode_step(cfg, max_len, mesh,
+                                        greedy=self.greedy, fused=self.fused)
+        #: how decode reaches the KV cache, "paged" or "gathered"
+        #: (``decode_kv_path``); the ``serve.decode`` span carries it
+        self.kv_path = decode.kv
+        self._pools = init_pools(cfg, num_blocks, block_size, self.kv_path)
         if self.tp > 1:
             # place shards once at init: TP params (heads/mlp/vocab over
             # the model axis), head-sharded pools when TP divides
@@ -508,10 +596,7 @@ class PagedEngine(Engine):
         self._tables = np.zeros((max_batch, self.blocks_per_seq), np.int32)
         self._seq_blocks: List[List[int]] = [[] for _ in range(max_batch)]
         self._prefilling: Dict[int, dict] = {}
-        self._paged_decode = jax.jit(
-            make_paged_decode_step(cfg, max_len, mesh,
-                                   greedy=self.greedy, fused=self.fused),
-            donate_argnums=(3,))
+        self._paged_decode = jax.jit(decode, donate_argnums=(3,))
         self._paged_extend = jax.jit(
             make_paged_extend_step(cfg, max_len, mesh, fused=self.fused),
             donate_argnums=(3,))
@@ -716,7 +801,7 @@ class PagedEngine(Engine):
                 if r is not None and i not in self._prefilling]
         if not live:
             return finished
-        with self._span("serve.decode", live=len(live)):
+        with self._span("serve.decode", live=len(live), kv=self.kv_path):
             for i in live:
                 assert self._pos[i] < self.max_len
                 self._ensure_block(i)

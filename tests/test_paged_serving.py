@@ -188,6 +188,77 @@ def test_paged_eos_frees_blocks_for_refill(paged_model):
     assert eng.allocator.free_blocks == eng.allocator.num_blocks - 1
 
 
+# -- paged decode reads the pool in place -----------------------------------
+
+def _streams(cfg, params, reqs, fused, **kw):
+    """Serve ``reqs`` on the interpret backend -> (engine, outputs)."""
+    with nn.backend("pallas_interpret"):
+        eng = mk_paged(cfg, params, fused=fused, **kw)
+        for prompt, budget in reqs:
+            eng.add_request(prompt, max_new_tokens=budget)
+        return eng, {r.uid: r.output for r in eng.run()}
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2])
+def test_paged_kernel_streams_match_gathered_path(paged_model, kv_heads):
+    """``PagedEngine(fused=True)`` on a kernel backend reads the pool in
+    place; its greedy streams equal the gathered path's (``fused=False``)
+    over mixed lengths, with EOS ending one request early and its slot
+    refilled from the queue. MHA and GQA 2:1."""
+    cfg, params = paged_model
+    if kv_heads != cfg.n_kv_heads:
+        cfg = cfg.replace(n_kv_heads=kv_heads)
+        params = init_lm(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(3)
+    reqs = [(rng.randint(1, cfg.vocab_size, size=n).tolist(), budget)
+            for n, budget in ((5, 6), (30, 4), (17, 8), (9, 3), (40, 5),
+                              (12, 7), (3, 4))]
+    _, probe = _streams(cfg, params, reqs, fused=False)
+    eos = probe[1][2]                    # request 1 stops early
+    gathered, want = _streams(cfg, params, reqs, fused=False, eos_id=eos)
+    paged, got = _streams(cfg, params, reqs, fused=True, eos_id=eos)
+    assert (gathered.kv_path, paged.kv_path) == ("gathered", "paged")
+    # only the paged path pads the pool's head dim to whole lane tiles
+    hd = cfg.resolved_head_dim
+    assert gathered._pools["scan"][0]["k"].shape[-1] == hd
+    assert paged._pools["scan"][0]["k"].shape[-1] == -(-hd // 128) * 128
+    assert len(want[1]) < len(probe[1]) and want[1][-1] == eos
+    assert got == want
+    assert paged.allocator.free_blocks + len(paged.prefix_cache) == \
+        paged.allocator.num_blocks - 1
+
+
+def _lowered(cfg, fused):
+    """(kv path, lowered paged_step text with op names) on the interpret
+    backend, at shapes only."""
+    from repro.models import init_lm_cache
+    from repro.serving.paged import make_paged_decode_step
+    with nn.backend("pallas_interpret"):
+        step = make_paged_decode_step(cfg, 64, fused=fused)
+        params = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg))
+        pools = jax.eval_shape(lambda: init_lm_cache(cfg, 9, 8))
+        ids = jax.ShapeDtypeStruct((3,), jnp.int32)
+        text = jax.jit(step).lower(
+            params, ids, ids, pools, jax.ShapeDtypeStruct((3, 8), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
+    return step.kv, text
+
+
+@pytest.mark.parametrize("case", ["fused", "unfused", "mla"])
+def test_paged_step_gathers_only_off_the_kernel_path(case):
+    """The fused step on a kernel backend holds no KV gather; the unfused
+    step and MLA configs keep the gathered view. All write their rows."""
+    cfg = tiny_cfg()
+    if case == "mla":
+        cfg = reduced(get_config("deepseek-v2-lite-16b")).replace(
+            loss_chunk=0)
+    kv, text = _lowered(cfg, fused=case != "unfused")
+    assert kv == ("paged" if case == "fused" else "gathered")
+    assert ("ng:memory:paged_kv_gather" in text) == (kv == "gathered")
+    assert "ng:memory:paged_kv_write" in text
+    assert ("ng:fused:fused_attn_decode" in text) == (case != "unfused")
+
+
 def test_paged_rejects_non_attention_mixers():
     cfg = reduced(get_config("recurrentgemma-2b")).replace(loss_chunk=0)
     params = init_lm(jax.random.PRNGKey(0), cfg)
@@ -360,9 +431,26 @@ def test_serve_spans_nest_and_name_their_requests(traced_serve):
         for s in by[name]:
             assert len(_inside(s, decodes)) == 1
     assert sum(d[3]["live"] for d in decodes) == eng.stats.decode_tokens
+    assert {d[3]["kv"] for d in decodes} == {eng.kv_path} == {"gathered"}
     # every request that decoded finished in some retirement
     assert sum(s[3]["finished"] for s in by["serve.retire"]) == \
         eng.stats.decoded_requests
+
+
+def test_serve_decode_span_names_the_paged_path(paged_model, tmp_path):
+    """On the kernel path every ``serve.decode`` span carries kv="paged"."""
+    cfg, params = paged_model
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng, _ = _streams(cfg, params, _span_requests(cfg)[:2], fused=True)
+    finally:
+        jax.profiler.stop_trace()
+    decodes = [s for s in _serve_spans(str(tmp_path))
+               if s[0] == "serve.decode"]
+    assert len(decodes) == eng.stats.decode_steps > 0
+    assert {d[3]["kv"] for d in decodes} == {"paged"}
 
 
 def test_tokens_identical_with_profiler_on_and_off(paged_model,

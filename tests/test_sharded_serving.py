@@ -209,6 +209,14 @@ def test_sharded_decode_parity(eight_devices):
     assert "parity_decode OK" in out
 
 
+@pytest.mark.multidevice
+def test_sharded_paged_kernel_parity(eight_devices):
+    """TP 2 and 4 with head-sharded pools take the paged decode kernel
+    inside shard_map and serve what the gathered path serves."""
+    out = eight_devices("sharded_serving_check.py", "parity_paged_kernel")
+    assert "parity_paged_kernel OK" in out
+
+
 @pytest.mark.slow
 @pytest.mark.multidevice
 def test_sharded_chunked_prefill_parity(eight_devices):
