@@ -63,6 +63,12 @@ class Drive:
     def in_window(self) -> List[Served]:
         return [s for s in self.served if s.due < self.end]
 
+    def steps_within(self, seconds: float) -> List[StepRecord]:
+        """The steps that started in the window and returned within
+        ``seconds`` of its start: those of a traced part that long."""
+        limit = self.t0 + seconds
+        return [r for r in self.steps if self.t0 <= r.start and r.end <= limit]
+
 
 def _prefix_counters(engine):
     pc = engine.prefix_cache

@@ -1,14 +1,14 @@
-"""Model FLOPs of the tokens emitted in the window over the chips' peak.
+"""Model FLOPs of the tokens emitted in the traced part over the chips' peak.
 
 A decoded token counts one forward pass at its depth; a first token counts
 its whole prompt. The FLOPs come from the configuration's shapes
-(``reference/<name>.py``), the time is the traced window.
+(``reference/<name>.py``), the time is the traced part's.
 """
 
 
 def read(ctx):
-    d, c = ctx.drive, ctx.costs
-    steps = [r for r in d.steps if d.t0 <= r.start < d.end]
+    c = ctx.costs
+    steps = ctx.drive.steps_within(ctx.trace.window_s)
     flops = sum(c.decode_token_flops(ctx.conf, n) for r in steps
                 for n in r.decode_positions)
     flops += sum(c.prompt_flops(ctx.conf, n) for r in steps

@@ -5,7 +5,27 @@ is run: the public ``config.json`` keys, with the keys the cell changed
 listed under ``reduced`` and the sizes set here under ``assumed``. Its
 ``program`` entry names the repository's own architecture and the overrides
 that make it serve this configuration; :func:`program_config` builds that and
-refuses to run when the two disagree on any size the reference reads.
+refuses to run when the two disagree on any size or scalar the reference
+reads.
+
+The scalars are matched by the program's ``ModelConfig`` field of the name
+below, where it has one (``None`` there means the neutral value); a program
+without the field serves the neutral value, so the file must hold it:
+
+====================== =========================== =========================
+file key               ``ModelConfig`` field       neutral value
+====================== =========================== =========================
+embedding_multiplier   ``embedding_multiplier``    1
+attention_multiplier   ``attention_multiplier``    1 / sqrt(head_dim)
+residual_multiplier    ``residual_multiplier``     1
+logits_scaling         ``logits_scaling``          1
+layer_norm_eps or      ``norm_eps``                1e-5 for LayerNorm, 1e-6
+rms_norm_eps, by norm                              for RMSNorm (``repro.nn``)
+====================== =========================== =========================
+
+A program that switches on what the reference does not describe (a logit
+softcap, a zero-centred or post-block norm, a query/key norm, scaled
+embeddings, a feed-forward bias) is refused.
 
 The weights are the benchmark's, not the program's: one jitted call makes
 every leaf on the device from the seed, in the type it is served in. Only the
@@ -50,6 +70,25 @@ def seed_rng(seed: int, stream: str) -> np.random.Generator:
     return np.random.default_rng([seed % 2 ** 64, salt])
 
 
+#: program switches the reference does not describe, each with its off value
+OFF = {"attn_logit_softcap": None, "final_logit_softcap": None,
+       "zero_centered_norm": False, "post_norm": False, "qk_norm": False,
+       "scale_embeddings": False, "ffn_bias": False}
+
+
+def _scalars(conf: dict) -> list:
+    """(file key, ModelConfig field, neutral value) of each scalar the
+    reference reads from ``conf`` (the table of the module's docstring)."""
+    eps = ("layer_norm_eps", 1e-5) if conf["norm"] == "layernorm" \
+        else ("rms_norm_eps", 1e-6)
+    return [("embedding_multiplier", "embedding_multiplier", 1.0),
+            ("attention_multiplier", "attention_multiplier",
+             1.0 / math.sqrt(conf["head_dim"])),
+            ("residual_multiplier", "residual_multiplier", 1.0),
+            ("logits_scaling", "logits_scaling", 1.0),
+            (eps[0], "norm_eps", eps[1])]
+
+
 def program_config(conf: dict):
     """The repository's ModelConfig that serves ``conf``, checked against it."""
     from repro.configs import get_config
@@ -75,12 +114,17 @@ def program_config(conf: dict):
     }
     got = {k: getattr(cfg, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
-    # what the program has no switch for must sit at its neutral value here
-    neutral = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
-               "logits_scaling": 1.0,
-               "attention_multiplier": 1.0 / math.sqrt(conf["head_dim"])}
-    bad.update({k: ("program has none", conf[k]) for k, v in neutral.items()
-                if not math.isclose(conf[k], v)})
+    for key, field, neutral in _scalars(conf):
+        if not hasattr(cfg, field):
+            if not math.isclose(conf[key], neutral):
+                bad[key] = (f"program has no {field}", conf[key])
+            continue
+        value = getattr(cfg, field)
+        value = neutral if value is None else value
+        if not math.isclose(conf[key], value):
+            bad[key] = (value, conf[key])
+    bad.update({k: (getattr(cfg, k), v) for k, v in OFF.items()
+                if getattr(cfg, k, v) != v})
     if bad:
         raise SystemExit(f"chipbench: program config {cfg.name} departs from "
                          f"{conf['name']}: {bad}")

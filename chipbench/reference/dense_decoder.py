@@ -91,8 +91,8 @@ def _layers(params):
 
 
 @partial(jax.jit, static_argnums=0)
-def _gaps(conf_items, params, tokens, targets):
-    """(S,) tokens -> per position, best logit - logit of the target."""
+def _logits(conf_items, params, tokens):
+    """(S,) tokens -> (S, vocab) logits, each position's next-token scores."""
     conf = dict(conf_items)
     x = params["embed"][tokens].astype(jnp.float32) \
         * conf["embedding_multiplier"]
@@ -108,7 +108,13 @@ def _gaps(conf_items, params, tokens, targets):
                             precision=HIGHEST)
     else:
         logits = _mm(h, params["head"])
-    logits = logits / conf["logits_scaling"]
+    return logits / conf["logits_scaling"]
+
+
+@partial(jax.jit, static_argnums=0)
+def _gaps(conf_items, params, tokens, targets):
+    """(S,) tokens -> per position, best logit - logit of the target."""
+    logits = _logits(conf_items, params, tokens)
     best = logits.max(-1)
     got = jnp.take_along_axis(logits, targets[:, None], -1)[:, 0]
     return best - got
@@ -118,6 +124,11 @@ _READS = ("norm", "layer_norm_eps", "rms_norm_eps", "partial_rotary_factor",
           "rope_theta", "num_attention_heads", "num_key_value_heads",
           "head_dim", "attention_multiplier", "residual_multiplier",
           "embedding_multiplier", "logits_scaling", "tie_word_embeddings")
+
+
+def conf_items(conf: dict) -> tuple:
+    """The keys of ``conf`` the forward pass reads, hashable for ``jit``."""
+    return tuple((k, conf.get(k)) for k in _READS)
 
 
 def served_gaps(conf: dict, params, prompt, served, pad_to: int) -> np.ndarray:
@@ -135,8 +146,8 @@ def served_gaps(conf: dict, params, prompt, served, pad_to: int) -> np.ndarray:
     toks[:len(seq) - 1] = seq[:-1]
     tgt = np.zeros(pad_to, np.int32)
     tgt[:len(seq) - 1] = seq[1:]
-    items = tuple((k, conf.get(k)) for k in _READS)
-    gaps = _gaps(items, params, jnp.asarray(toks), jnp.asarray(tgt))
+    gaps = _gaps(conf_items(conf), params, jnp.asarray(toks),
+                 jnp.asarray(tgt))
     lo = len(prompt) - 1
     return np.asarray(gaps)[lo:lo + len(served)]
 

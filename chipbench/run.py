@@ -9,8 +9,9 @@ under ``nn.backend("pallas")``; warm up only the cell's own shapes with a
 token-remapped shadow of its traffic; serve the seeded traffic open loop for
 ``--seconds``; check the served tokens against the configuration's plain
 reference; print one JSON line. ``--trace 0`` reports the cell's end-to-end
-metrics and runs no profiler; ``--trace 1`` traces the window and reports
-its per-layer metrics, the device's busy time and a breakdown.
+metrics and runs no profiler; ``--trace 1`` traces the first ``TRACE_S``
+seconds of the window and reports their per-layer metrics, the device's
+busy time, how much of that part the trace covers, and a breakdown.
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 configuration and the traffic mix, whose files sit under ``configs/`` and
@@ -50,6 +51,16 @@ import jax  # noqa: E402
 
 from chipbench import check as checkmod  # noqa: E402
 from chipbench import driver, model, workload  # noqa: E402
+
+
+#: seconds of the window that a ``--trace 1`` run traces. The profiler keeps
+#: every device op in memory and drops those past a few million: the decode
+#: cell, at about 1600 ops a step and 64 steps a second, lost the last 14 s
+#: of a 51 s window. 20 s is about a third of that limit, with room for a
+#: faster engine. Stopping the profiler holds the host for over a minute
+#: there, so a traced run serves little past the traced part; what it
+#: served is checked as in any run.
+TRACE_S = 20.0
 
 
 class NoChip(RuntimeError):
@@ -232,28 +243,39 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
             f"{counter.cache[counter.HIT]} from the compile cache, "
             f"{counter.cache[counter.MISS]} not found there")
 
-        state = {"tracing": False}
+        # the traced part ends at the first step that returns TRACE_S into
+        # the window; the profiler stops one step later, so that a trace
+        # not cut short holds device ops past window.end
+        phase = {"now": None}
         if traced:
             tdir = tempfile.mkdtemp(prefix="chipbench-trace-")
             jax.profiler.start_trace(tdir, profiler_options=trace_options())
-            state["tracing"] = True
+            phase["now"] = "tracing"
 
         def on_step(rec):
-            if state["tracing"] and rec.end >= d_end[0]:
+            if phase["now"] == "tracing" and rec.end >= trace_until:
                 with jax.profiler.TraceAnnotation("window.end"):
                     pass
-                jax.profiler.stop_trace()
-                state["tracing"] = False
+                phase["now"] = "closing"
+            elif phase["now"] == "closing":
+                stop_trace()
 
-        d_end = [time.perf_counter() + seconds]
+        def stop_trace():
+            t = time.perf_counter()
+            jax.profiler.stop_trace()
+            phase["now"] = None
+            log(f"profiler stopped in {time.perf_counter() - t:.3f} s")
+
+        trace_until = time.perf_counter() + min(TRACE_S, seconds)
         with jax.profiler.TraceAnnotation("window.start"):
             pass
         d = driver.drive(eng, reqs, seconds, drain=bool(mix.get("drain")),
                          on_step=on_step)
-        if state["tracing"]:
+        if phase["now"] == "tracing":
             with jax.profiler.TraceAnnotation("window.end"):
                 pass
-            jax.profiler.stop_trace()
+        if phase["now"] is not None:
+            stop_trace()
         inside = counter.names[warm:]
         log(f"compiles inside the window: {len(inside)} {inside}")
         peak = max((x.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -294,6 +316,8 @@ def run_cell(bench: dict, cell_name: str, seed: int, seconds: float,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         device["busy_s"] = red.busy_s
         device["window_s"] = red.window_s
+        device["traced_s"] = red.traced_s
+        device["covered_s"] = red.covered_s
         out["metrics"] = metrics
         out["device"] = device
         out["breakdown"] = red.breakdown()

@@ -31,8 +31,8 @@ program without them leaves it. To see them on the chip:
 runs the cell once as ``--trace 1`` does and prints its result line with
 two more sections: ``serve`` (the above, and the count and mean host time
 of each span) and ``traced_window`` (the end-to-end numbers of the traced
-window on the benchmark's clock, closed at the step that stopped the
-trace, for the profiler's cost). It runs ``run.run_cell`` as it is and
+part on the benchmark's clock, closed at its last step, for the profiler's
+cost). It runs ``run.run_cell`` as it is and
 takes the trace and the drive from ``chipbench.trace.reduce_dir`` and
 ``chipbench.driver.drive``, wrapped while the run lasts.
 """
@@ -270,11 +270,12 @@ def main(argv=None) -> int:
         run.log(f"chipbench: {e}; nothing is served without the chip")
         return 2
     out["serve"] = summary(got["trace"], got["spans"])
-    d = got["drive"]
-    # the trace stopped at the return of the first step past the window;
-    # the drive closed later, once writing the trace let it
-    stop = min((r.end for r in d.steps if r.end >= d.end), default=d.close)
-    out["traced_window"] = run.end_to_end(dataclasses.replace(d, close=stop))
+    d, red = got["drive"], got["trace"]
+    # the traced part closes at the return of its last step
+    steps = d.steps_within(red.window_s)
+    stop = steps[-1].end if steps else d.t0
+    out["traced_window"] = run.end_to_end(dataclasses.replace(
+        d, end=d.t0 + red.window_s, close=stop))
     print(json.dumps(out), flush=True)
     return 0
 

@@ -4,7 +4,7 @@ A synthetic trace holds decode calls that take exactly the least time the
 cost functions allow (the roofline itself) and calls that take longer. The
 readers must give 100% for the first and less for the second: the FLOPs
 and bytes are not counted high, and no part of the work is left out of the
-time.
+time. A trace cut short is read only as far as it holds device ops.
 """
 
 import json
@@ -16,6 +16,7 @@ import pytest
 
 from chipbench import driver, model, run, trace
 from chipbench.reference import dense_decoder as dd
+from chipbench.tests.test_trace import NS, SYN_HLO, ev
 
 HERE = Path(__file__).resolve().parents[1]
 PEAKS = json.loads((HERE / "peaks.json").read_text())["devices"]["TPU v5 lite"]
@@ -28,25 +29,31 @@ def config(name):
     return model.load_config(name)
 
 
-def _ctx(conf, rows_per_step, stretch):
-    """One decode call per step, each taking ``stretch`` x its least time."""
-    ops, steps, t = [], [], 0
+def _ctx(conf, rows_per_step, stretch, covered=None):
+    """One decode call per step, each taking ``stretch`` x its least time,
+    in a trace of them all whose device ops stop after the first
+    ``covered`` calls, as a trace the profiler cut short."""
+    modules, ops, steps, t = [], [], [], 0
     flop_s, byte_s = PEAKS["bf16_flops_per_s"], PEAKS["hbm_bytes_per_s"]
-    for rows in rows_per_step:
+    for i, rows in enumerate(rows_per_step):
         f, b = dd.decode_step_cost(conf, rows)
         fa, ba = dd.decode_attention_cost(conf, rows)
         least = max(f / flop_s, b / byte_s)
         attn = max(fa / flop_s, ba / byte_s)
         dur = math.ceil(least * stretch * 1e9)
         a = math.ceil(attn * stretch * 1e9)
-        ops.append(trace.Op(trace.DECODE, "fusion.1", "gemm", "linear",
-                            t, t + dur - a))
-        ops.append(trace.Op(trace.DECODE, "custom-call.2", "fused",
-                            "fused_attn_decode", t + dur - a, t + dur))
+        if covered is None or i < covered:
+            modules.append(ev("jit_paged_step(1)", t, dur))
+            ops.append(ev("fusion.1", t, dur - a))
+            ops.append(ev("custom-call.2", t + dur - a, a))
         steps.append(driver.StepRecord(t / 1e9, (t + dur) / 1e9, list(rows),
                                        len(rows), 0, []))
         t += dur
-    red = trace.Reduction((0, t), ops, [(trace.DECODE, int(r.start * 1e9), int(r.end * 1e9)) for r in steps], [])
+    host = NS(name="/host:CPU", lines=[NS(name="python", events=[
+        ev("window.start", 0, 0), ev("window.end", t, 0)])])
+    device = NS(name="/device:TPU:0", lines=[
+        NS(name="XLA Modules", events=modules), NS(name="XLA Ops", events=ops)])
+    red = trace.reduce_profile(NS(planes=[host, device]), SYN_HLO)
     d = driver.Drive(0.0, t / 1e9, [], steps, 0, 0)
     return SimpleNamespace(conf=conf, drive=d, trace=red, peaks=PEAKS,
                            costs=dd, chips=1, seconds=t / 1e9)
@@ -64,6 +71,26 @@ def test_share_at_the_roofline_is_at_most_100(name, metric):
     assert 0 < slow < at <= 100.0 + 1e-9
     if metric != "mfu":
         assert at == pytest.approx(100.0, rel=1e-3)
+
+
+@pytest.mark.parametrize("metric", ["decode_roofline", "attn_decode_roofline",
+                                    "mfu"])
+def test_a_cut_trace_counts_only_the_covered_steps(metric):
+    """The device ops stop after 6 of 8 calls at the roofline: the readers
+    stop there too, and read what the 6 calls alone read (100% for the
+    rooflines), not 133% (all steps over the covered time) or 75% (the
+    covered steps over the whole traced part)."""
+    conf = model.load_config("stablelm-3b")
+    rows = [[64 + 37 * i + 5 * j for i in range(8)] for j in range(8)]
+    ctx = _ctx(conf, rows, 1.0, covered=6)
+    assert ctx.trace.covered_s == pytest.approx(0.75 * ctx.trace.traced_s,
+                                                rel=0.1)
+    assert ctx.trace.window_s == ctx.trace.covered_s
+    read = run._metric_reader(metric)
+    assert read(ctx) == pytest.approx(read(_ctx(conf, rows[:6], 1.0)),
+                                      rel=1e-9)
+    if metric != "mfu":
+        assert read(ctx) == pytest.approx(100.0, rel=1e-3)
 
 
 def test_no_trace_no_share():

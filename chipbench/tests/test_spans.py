@@ -12,16 +12,19 @@ def nested():
     """One decode module with four ops, one ``engine.step`` around an
     admission and a decode step, and the engine's spans inside it.
 
-    ops [100, 150) [160, 200) [260, 300) [400, 450) in a [50, 500) window;
+    ops [100, 150) [160, 200) [260, 300) [400, 450) in a [50, 500) window,
+    and the step past it that the benchmark traces, [510, 530);
     idle gaps (midpoint -> innermost span): (50, 100) 75 none,
     (150, 160) 155 serve.prefill, (200, 260) 230 serve.decode.prep,
     (300, 400) 350 serve.decode, (450, 500) 475 engine.step.
     """
     op = "%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kOutput"
     device = NS(name="/device:TPU:0", lines=[
-        NS(name="XLA Modules", events=[ev("jit_paged_step(1)", 100, 350)]),
+        NS(name="XLA Modules", events=[ev("jit_paged_step(1)", 100, 350),
+                                       ev("jit_paged_step(1)", 510, 20)]),
         NS(name="XLA Ops", events=[ev(op, 100, 50), ev(op, 160, 40),
-                                   ev(op, 260, 40), ev(op, 400, 50)])])
+                                   ev(op, 260, 40), ev(op, 400, 50),
+                                   ev(op, 510, 20)])])
     host = NS(name="/host:CPU", lines=[NS(name="python", events=[
         ev("window.start", 50, 0),
         ev("engine.step", 80, 400),
@@ -80,7 +83,7 @@ def test_the_three_quantities():
     assert spans.admit_ms(red, got) == pytest.approx(130e-6)
     assert spans.stalls(red, got, min_s=100e-9) == [
         [pytest.approx(250e-9), pytest.approx(100e-9), "serve.decode",
-         "in paged_step", "none"]]
+         "in paged_step", "paged_step"]]
     assert spans.stalls(red, got) == []
     s = spans.summary(red, got)
     assert s["spans"]["serve.decode"] == {"count": 1, "mean_ms": 255e-6,
@@ -149,6 +152,9 @@ def test_capture_keeps_the_spans_of_a_traced_run():
         out = tiny.run(traced=True, seconds=1.0)
     assert (trace.reduce_dir, driver.drive) == (reduce_dir, drive)
     assert out["correct"]
+    # a window shorter than the traced part is traced whole
+    assert out["device"]["traced_s"] == pytest.approx(1.0, abs=0.1)
+    assert out["device"]["window_s"] <= out["device"]["traced_s"]
     d, red = got["drive"], got["trace"]
     names = {s.name for s in got["spans"]}
     assert {"serve.admit", "serve.prefill", "serve.scatter", "serve.decode",

@@ -35,13 +35,14 @@ def synthetic():
     """Two decode executions and one prefill on a device, host spans around.
 
     decode 1: [100, 200) gemm 60 + attention 30, 10 idle inside the module;
-    prefill:  [250, 300) one untagged copy; decode 2: [400, 450) gemm 50.
-    On a TPU an op event is named by its instruction's text.
+    prefill:  [250, 300) one untagged copy; decode 2: [400, 450) gemm 50;
+    decode 3: [510, 530), past ``window.end`` as the benchmark traces one
+    step more. On a TPU an op event is named by its instruction's text.
     """
     device = NS(name="/device:TPU:0", lines=[
         NS(name="XLA Modules", events=[
             ev("jit_paged_step(1)", 100, 100), ev("jit_prefill_step(2)", 250, 50),
-            ev("jit_paged_step(1)", 400, 50)]),
+            ev("jit_paged_step(1)", 400, 50), ev("jit_paged_step(1)", 510, 20)]),
         NS(name="XLA Ops", events=[
             ev("%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kOutput",
                100, 60),
@@ -49,7 +50,9 @@ def synthetic():
                160, 30),
             ev("%copy.3 = bf16[8]{0} copy(bf16[8]{0} %r)", 250, 50),
             ev("%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kOutput",
-               400, 50)]),
+               400, 50),
+            ev("%fusion.1 = bf16[8]{0} fusion(bf16[8]{0} %p), kind=kOutput",
+               510, 20)]),
     ])
     host = NS(name="/host:CPU", lines=[NS(name="python", events=[
         ev("window.start", 50, 0), ev("engine.step", 90, 120),
@@ -101,6 +104,24 @@ def test_busy_idle_and_breakdown():
                     "idle": pytest.approx(50e-9),
                     "no host span": pytest.approx(50e-9)}
     assert sum(idle.values()) == pytest.approx(red.window_s - red.busy_s)
+
+
+def test_a_trace_cut_short_ends_at_its_last_device_op():
+    """A whole trace holds the step past ``window.end``; one the profiler
+    cut short is read only as far as its device ops go."""
+    red = trace.reduce_profile(synthetic(), SYN_HLO)
+    assert (red.traced_s, red.covered_s) == (pytest.approx(450e-9),
+                                             pytest.approx(480e-9))
+    assert red.window == (50, 500)
+    pd = synthetic()
+    for line in pd.planes[1].lines:
+        line.events.pop()
+    red = trace.reduce_profile(pd, SYN_HLO)
+    assert (red.traced_s, red.covered_s) == (pytest.approx(450e-9),
+                                             pytest.approx(400e-9))
+    assert red.window == (50, 450)
+    assert red.busy_s == pytest.approx(190e-9)
+    assert red.gaps() == [(50, 100), (190, 250), (300, 400)]
 
 
 def test_ops_without_module_stat_belong_to_the_enclosing_module():

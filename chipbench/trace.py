@@ -13,11 +13,14 @@ for an untagged fusion the primitive that ends its op_name, as in the
 taxonomy's fallback (a copy of its tables is kept here so that the
 yardstick does not move with the program).
 
-The window is the span between the ``window.start`` and ``window.end``
-host annotations the benchmark writes. Busy time is the union of the
-device op intervals inside it; each idle gap is put down to the benchmark's
-host span (``submit``, ``engine.step``, ``bookkeeping``, ``idle``) that
-covers the gap's midpoint.
+The traced part is the span between the ``window.start`` and
+``window.end`` host annotations the benchmark writes; the benchmark stops
+the profiler one engine step after ``window.end``, so a whole trace holds
+device ops past it. A trace the profiler cut short holds none: the window
+the metrics read then ends at its last device op. Busy time is the union of
+the device op intervals inside the window; each idle gap is put down to the
+benchmark's host span (``submit``, ``engine.step``, ``bookkeeping``,
+``idle``) that covers the gap's midpoint.
 """
 
 from __future__ import annotations
@@ -151,10 +154,14 @@ class Op:
 
 @dataclasses.dataclass
 class Reduction:
+    #: what the metrics read: the traced part, up to the last device op
     window: Tuple[int, int]
     ops: List[Op]
     runs: List[Tuple[str, int, int]]        # (program, start, end) executions
     host: List[Tuple[str, int, int]]
+    #: ``window.start`` to ``window.end``, and to the last device op
+    traced_s: float = 0.0
+    covered_s: float = 0.0
 
     @property
     def window_s(self) -> float:
@@ -308,7 +315,9 @@ def reduce_profile(pd, hlo: Dict[str, str]) -> Reduction:
         ops += _own_times(line_ops)
     lo = marks.get("window.start", min((o.start for o in ops), default=0))
     hi = marks.get("window.end", max((o.end for o in ops), default=0))
-    return Reduction((lo, hi), ops, runs, host)
+    last = max((o.end for o in ops), default=hi)
+    return Reduction((lo, min(hi, last)), ops, runs, host,
+                     traced_s=(hi - lo) / 1e9, covered_s=(last - lo) / 1e9)
 
 
 def reduce_dir(log_dir: str, hlo: Dict[str, str]) -> Reduction:
