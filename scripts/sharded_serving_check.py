@@ -15,7 +15,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 #
 # Usage: python scripts/sharded_serving_check.py \
 #            <parity_decode|parity_paged_kernel|parity_chunked|
-#             parity_prefix|bench>
+#             parity_prefix|parity_granite|bench>
 
 import json
 import sys
@@ -40,20 +40,21 @@ MAX_LEN = 64
 MAX_BATCH = 4
 BLOCK = 8
 
-_cfg = None
-_params = None
+#: KV heads where an arch keeps GQA at the sweep's 8 query heads
+KV_HEADS = {"granite-3-8b": 4}
+_models = {}
 
 
-def cfg_params():
-    global _cfg, _params
-    if _cfg is None:
-        _cfg = sharded_serving_config(ARCH)
-        _params = init_lm(jax.random.PRNGKey(0), _cfg)
-    return _cfg, _params
+def cfg_params(arch: str = ARCH):
+    if arch not in _models:
+        cfg = sharded_serving_config(arch)
+        cfg = cfg.replace(n_kv_heads=KV_HEADS.get(arch, cfg.n_kv_heads))
+        _models[arch] = cfg, init_lm(jax.random.PRNGKey(0), cfg)
+    return _models[arch]
 
 
-def make_engine(tp: int, **kw):
-    cfg, params = cfg_params()
+def make_engine(tp: int, arch: str = ARCH, **kw):
+    cfg, params = cfg_params(arch)
     mesh = make_sim_mesh(1, tp) if tp > 1 else None
     return PagedEngine(cfg, params, max_batch=MAX_BATCH, max_len=MAX_LEN,
                        block_size=BLOCK, mesh=mesh, **kw)
@@ -107,6 +108,27 @@ def main(mode: str) -> int:
                     f"tp={tp} paged-kernel streams diverge from the "
                     f"gathered path:\n  gathered: {ref}\n  paged:    {out}")
         print("parity_paged_kernel OK")
+        return 0
+
+    if mode == "parity_granite":
+        # granite's scalar multipliers (embedding, attention, residual,
+        # logits) and RMSNorm eps inside shard_map: TP 2 and 4 read the
+        # head-sharded GQA pools through the paged kernel, and a second
+        # wave hits the prefix cache and prefills through lm_extend; the
+        # streams must equal the single-device gathered path's
+        cfg, _ = cfg_params("granite-3-8b")
+        assert cfg.residual_multiplier is not None and cfg.n_kv_heads == 4
+        base = prompts(3, 24, 32)
+        wave2 = [p[:16] + q for p, q in zip(base, prompts(3, 4, 8, seed=11))]
+        with nn.backend("pallas_interpret"):
+            outs = {}
+            for tp in (1, 2, 4):
+                eng = make_engine(tp, "granite-3-8b", fused=tp > 1)
+                assert eng.kv_path == ("paged" if tp > 1 else "gathered")
+                outs[tp] = (serve(eng, base), serve(eng, wave2))
+                assert eng.prefix_cache.hits > 0, tp
+        assert outs[2] == outs[1] and outs[4] == outs[1], outs
+        print("parity_granite OK")
         return 0
 
     if mode == "parity_chunked":

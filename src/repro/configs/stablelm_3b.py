@@ -1,5 +1,6 @@
 """stablelm-3b [dense] — 32L d_model=2560 32H (MHA kv=32) d_ff=6912
-vocab=50304 [hf:stabilityai/stablelm-2-1_6b lineage].
+vocab=50304, from StableLM-3B-4E1T
+(https://huggingface.co/stabilityai/stablelm-3b-4e1t, config.json).
 
 StableLM blocks: LayerNorm, partial rotary embedding on 25% of head dims,
 SwiGLU FFN, untied embeddings.

@@ -44,6 +44,13 @@ def pos_vector(pos, batch: int):
     return pos
 
 
+def attn_scale(dk: int, multiplier: Optional[float] = None) -> float:
+    """The factor on ``q . k`` before the softmax, the one place it is
+    decided: the config's ``attention_multiplier`` where it sets one
+    (granite's 1/128), else ``1 / sqrt(dk)``."""
+    return 1.0 / math.sqrt(dk) if multiplier is None else float(multiplier)
+
+
 def _softcap(s, cap: Optional[float]):
     if cap is None:
         return s
@@ -59,8 +66,10 @@ def chunked_attention(q, k, v, *, causal: bool = True,
                       q_offset: int = 0,
                       chunk_q: int = 512, chunk_kv: int = 1024,
                       softcap: Optional[float] = None,
-                      triangular: bool = False):
+                      triangular: bool = False,
+                      scale: Optional[float] = None):
     """q: (B, Sq, Hq, Dh); k, v: (B, Skv, Hkv, Dv). Returns (B, Sq, Hq, Dv).
+    ``scale`` multiplies ``q . k`` (default :func:`attn_scale` of Dh).
 
     ``triangular=True`` skips KV chunks that are fully masked for the current
     query chunk (dynamic ``fori_loop`` bound) — a compute-roofline
@@ -70,7 +79,7 @@ def chunked_attention(q, k, v, *, causal: bool = True,
     b, sq, hq, dh = q.shape
     _, skv, hkv, dv = v.shape
     g = hq // hkv
-    scale = 1.0 / math.sqrt(dh)
+    scale = attn_scale(dh, scale)
 
     cq = min(chunk_q, sq)
     ck = min(chunk_kv, skv)
@@ -170,13 +179,12 @@ def chunked_attention(q, k, v, *, causal: bool = True,
 # roofline analyzer recognizes as a single-kernel region.
 
 def _flash_fwd_impl(q, k, v, causal, window, q_offset, chunk_q, chunk_kv,
-                    softcap):
+                    softcap, scale):
     """Head-flat flash forward: q, k, v all (B, S, H, *) — GQA expansion
     happens in the wrapper so H shards cleanly over the model axis even
     when kv_heads < TP degree. Returns (out, lse (B, H, Sq) f32)."""
     b, sq, h, dh = q.shape
     _, skv, _, dv = v.shape
-    scale = 1.0 / math.sqrt(dh)
     cq = min(chunk_q, sq)
     ck = min(chunk_kv, skv)
     nq = -(-sq // cq)
@@ -241,11 +249,10 @@ def _flash_fwd_impl(q, k, v, causal, window, q_offset, chunk_q, chunk_kv,
 
 
 def _flash_bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset,
-                    chunk_q, chunk_kv):
+                    chunk_q, chunk_kv, scale):
     """Head-flat flash backward: recompute tiles; never stores (S, S)."""
     b, sq, h, dh = q.shape
     _, skv, _, dv = v.shape
-    scale = 1.0 / math.sqrt(dh)
     cq = min(chunk_q, sq)
     ck = min(chunk_kv, skv)
     nq = -(-sq // cq)
@@ -324,24 +331,26 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, causal, window, q_offset,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, causal, window, q_offset, chunk_q, chunk_kv):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, causal, window, q_offset, chunk_q, chunk_kv, scale):
     out, _ = _flash_fwd_impl(q, k, v, causal, window, q_offset, chunk_q,
-                             chunk_kv, None)
+                             chunk_kv, None, scale)
     return out
 
 
-def _flash_core_fwd(q, k, v, causal, window, q_offset, chunk_q, chunk_kv):
+def _flash_core_fwd(q, k, v, causal, window, q_offset, chunk_q, chunk_kv,
+                    scale):
     out, lse = _flash_fwd_impl(q, k, v, causal, window, q_offset, chunk_q,
-                               chunk_kv, None)
+                               chunk_kv, None, scale)
     return out, (q, k, v, out, lse)
 
 
-def _flash_core_bwd(causal, window, q_offset, chunk_q, chunk_kv, res, dout):
+def _flash_core_bwd(causal, window, q_offset, chunk_q, chunk_kv, scale, res,
+                    dout):
     q, k, v, out, lse = res
     with jax.named_scope(nn.scope_tag(OpGroup.GEMM, "flash_attention")):
         dq, dk, dv = _flash_bwd_impl(q, k, v, out, lse, dout, causal, window,
-                                     q_offset, chunk_q, chunk_kv)
+                                     q_offset, chunk_q, chunk_kv, scale)
     return dq, dk, dv
 
 
@@ -351,8 +360,10 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 def flash_attention_jnp(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None, q_offset: int = 0,
                         chunk_q: int = 512, chunk_kv: int = 1024,
-                        softcap: Optional[float] = None):
-    """Flash attention (jnp twin of kernels/flash_attention.py).
+                        softcap: Optional[float] = None,
+                        scale: Optional[float] = None):
+    """Flash attention (jnp twin of kernels/flash_attention.py); ``scale``
+    multiplies ``q . k`` (default :func:`attn_scale` of the head dim).
 
     GQA is expanded to head-flat form *outside* the custom-VJP core: the
     per-q-head KV gather shards cleanly over the model axis even when
@@ -362,10 +373,12 @@ def flash_attention_jnp(q, k, v, *, causal: bool = True,
     gives the group-summed dk/dv for free. Softcap falls back to the plain
     chunked path (no assigned arch softcaps attention).
     """
+    scale = attn_scale(q.shape[-1], scale)
     if softcap is not None:
         return chunked_attention(q, k, v, causal=causal, window=window,
                                  q_offset=q_offset, chunk_q=chunk_q,
-                                 chunk_kv=chunk_kv, softcap=softcap)
+                                 chunk_kv=chunk_kv, softcap=softcap,
+                                 scale=scale)
     from repro.sharding import shard
 
     hq, hkv = q.shape[2], k.shape[2]
@@ -380,7 +393,7 @@ def flash_attention_jnp(q, k, v, *, causal: bool = True,
     with jax.named_scope(nn.scope_tag(OpGroup.GEMM, "flash_attention")):
         out = _flash_core(q, k, v, causal, window, q_offset,
                           min(chunk_q, q.shape[1]),
-                          min(chunk_kv, k.shape[1]))
+                          min(chunk_kv, k.shape[1]), scale)
     return shard(out, "batch", None, "heads", None)
 
 
@@ -433,15 +446,16 @@ def _qkv(params, x, cfg: ModelConfig, positions):
 def _attention_impl(q, k, v, cfg: ModelConfig, window, q_offset: int = 0):
     """Backend dispatch: Pallas flash kernel vs the flash-VJP jnp twin."""
     backend = nn.get_backend()
+    scale = attn_scale(cfg.resolved_head_dim, cfg.attention_multiplier)
     if backend != "jnp" and cfg.attn_logit_softcap is None:
         from repro.kernels import ops as kops
         return kops.flash_attention(
             q, k, v, causal=cfg.causal, window=window, q_offset=q_offset,
-            interpret=nn.kernel_interpret())
+            scale=scale, interpret=nn.kernel_interpret())
     return flash_attention_jnp(
         q, k, v, causal=cfg.causal, window=window, q_offset=q_offset,
         chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
-        softcap=cfg.attn_logit_softcap)
+        softcap=cfg.attn_logit_softcap, scale=scale)
 
 
 def attn_forward(params, x, cfg: ModelConfig, kind: str, positions):
@@ -522,6 +536,7 @@ def attn_decode(params, x, cfg: ModelConfig, kind: str, cache: dict,
     b = x.shape[0]
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     g = hq // hkv
+    scale = attn_scale(hd, cfg.attention_multiplier)
     pos = pos_vector(pos, b)
     positions = pos[:, None]
     q, k_new, v_new = _qkv(params, x, cfg, positions)
@@ -553,19 +568,18 @@ def attn_decode(params, x, cfg: ModelConfig, kind: str, cache: dict,
     backend = nn.get_backend()
     if nn.fusion_enabled():
         # one fused operator (attn_template:decode on kernel backends)
-        o = nn.fused_attn_decode(q, k, v, lengths,
+        o = nn.fused_attn_decode(q, k, v, lengths, scale=scale,
                                  softcap=cfg.attn_logit_softcap)
         o = o.reshape(b, 1, hq * hd).astype(x.dtype)
         return nn.linear(o, params["wo"].astype(x.dtype)), new_cache
     if backend != "jnp":
         from repro.kernels import ops as kops
         o = kops.attn_decode_template(
-            q, k, v, lengths, softcap=cfg.attn_logit_softcap,
+            q, k, v, lengths, scale=scale, softcap=cfg.attn_logit_softcap,
             interpret=nn.kernel_interpret())
         o = o.reshape(b, 1, hq * hd).astype(x.dtype)
         return nn.linear(o, params["wo"].astype(x.dtype)), new_cache
 
-    scale = 1.0 / math.sqrt(hd)
     qh = q.reshape(b, hkv, g, hd)
     with jax.named_scope(nn.scope_tag(OpGroup.GEMM, "attn_qk")):
         # KV stays bf16 in HBM; f32 accumulate on the MXU. An explicit
@@ -630,7 +644,8 @@ def attn_decode_paged(params, x, cfg: ModelConfig, cache: PagedKV,
                        for t in _qkv(params, x, cfg, pos[:, None]))
     k = nn.paged_kv_write(cache.k, k_new, cache.tables, pos, cache.layer)
     v = nn.paged_kv_write(cache.v, v_new, cache.tables, pos, cache.layer)
-    o = nn.fused_attn_decode(q, k, v, pos + 1, scale=1.0 / math.sqrt(hd),
+    o = nn.fused_attn_decode(q, k, v, pos + 1,
+                             scale=attn_scale(hd, cfg.attention_multiplier),
                              softcap=cfg.attn_logit_softcap,
                              tables=cache.tables, layer=cache.layer)
     o = fit_head(o, hd).reshape(b, 1, hq * hd).astype(x.dtype)
@@ -667,7 +682,8 @@ def attn_extend(params, x, cfg: ModelConfig, kind: str, cache: dict,
     out = chunked_attention(
         q, k, v, causal=cfg.causal, q_offset=start,
         chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
-        softcap=cfg.attn_logit_softcap)
+        softcap=cfg.attn_logit_softcap,
+        scale=attn_scale(cfg.resolved_head_dim, cfg.attention_multiplier))
     y = nn.linear(nn.merge_heads(out), params["wo"].astype(x.dtype))
     return y, {"k": k, "v": v}
 
@@ -691,6 +707,12 @@ def init_mla(key, cfg: ModelConfig) -> dict:
         "w_uv": dense_init(ks[4], (r, h, vd), dtype=pd),
         "wo": dense_init(ks[5], (h * vd, d), dtype=pd),
     }
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    """MLA scores over the concatenated nope + rope query/key dims."""
+    return attn_scale(cfg.qk_nope_dim + cfg.qk_rope_dim,
+                      cfg.attention_multiplier)
 
 
 def _mla_q(params, x, cfg: ModelConfig, positions):
@@ -723,18 +745,19 @@ def mla_forward(params, x, cfg: ModelConfig, positions):
         [k_nope, jnp.broadcast_to(kr[:, :, None, :],
                                   (*kr.shape[:2], h, cfg.qk_rope_dim))],
         axis=-1)
+    scale = _mla_scale(cfg)
     backend = nn.get_backend()
     if backend != "jnp":
         # the causal template handles Dv != Dk (nope+rope keys, v_head_dim
         # values), so MLA prefill routes through the same Pallas body
         from repro.kernels import ops as kops
         out = kops.flash_attention(
-            q, k, v, causal=cfg.causal,
+            q, k, v, causal=cfg.causal, scale=scale,
             interpret=nn.kernel_interpret())
     else:
         out = flash_attention_jnp(q, k, v, causal=cfg.causal,
                                   chunk_q=cfg.attn_chunk_q,
-                                  chunk_kv=cfg.attn_chunk_kv)
+                                  chunk_kv=cfg.attn_chunk_kv, scale=scale)
     return nn.linear(out.reshape(*x.shape[:2], h * vd),
                      params["wo"].astype(x.dtype))
 
@@ -780,7 +803,7 @@ def mla_decode(params, x, cfg: ModelConfig, cache: dict, pos):
 
     # absorb W_uk into the query: score in latent space
     q_lat = nn.einsum("bqhn,rhn->bqhr", q_nope, params["w_uk"].astype(x.dtype))
-    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    scale = _mla_scale(cfg)
     backend = nn.get_backend()
     if nn.fusion_enabled() or backend != "jnp":
         # decode-1q spec over the latent cache: q/k live in the
@@ -854,7 +877,7 @@ def mla_extend(params, x, cfg: ModelConfig, cache: dict,
         axis=-1)
     out = chunked_attention(q, k, v, causal=cfg.causal, q_offset=start,
                             chunk_q=cfg.attn_chunk_q,
-                            chunk_kv=cfg.attn_chunk_kv)
+                            chunk_kv=cfg.attn_chunk_kv, scale=_mla_scale(cfg))
     y = nn.linear(out.reshape(b, c_len, h * cfg.v_head_dim),
                   params["wo"].astype(x.dtype))
     return y, {"c": c, "kr": kr}

@@ -100,6 +100,14 @@ class ModelConfig:
     final_logit_softcap: Optional[float] = None
     input_mode: str = "tokens"              # tokens | embeddings (stub frontend)
 
+    # scalar multipliers (granite). None is the neutral value, and a neutral
+    # multiplier emits no op at all
+    embedding_multiplier: Optional[float] = None  # x *= m after the lookup
+    attention_multiplier: Optional[float] = None  # q.k scale (1/sqrt(hd))
+    residual_multiplier: Optional[float] = None   # branch *= m before its add
+    logits_scaling: Optional[float] = None        # logits /= s
+    norm_eps: Optional[float] = None              # repro.nn's default per norm
+
     # numerics / execution
     dtype: str = "bfloat16"                 # activation/compute dtype
     param_dtype: str = "float32"

@@ -81,12 +81,18 @@ def _init_norm(cfg: ModelConfig):
     return {"scale": jnp.ones((d,), pd)}
 
 
+def _eps(cfg: ModelConfig) -> dict:
+    """The norms' ``eps`` keyword: none where the config keeps
+    ``repro.nn``'s default for its norm."""
+    return {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+
+
 def _apply_norm(p, x, cfg: ModelConfig):
     if cfg.norm == "layernorm":
         return nn.layer_norm(x, p["scale"].astype(x.dtype),
-                             p["bias"].astype(x.dtype))
+                             p["bias"].astype(x.dtype), **_eps(cfg))
     return nn.rms_norm(x, p["scale"].astype(x.dtype),
-                       zero_centered=cfg.zero_centered_norm)
+                       zero_centered=cfg.zero_centered_norm, **_eps(cfg))
 
 
 def _add_norm(p, a, x, cfg: ModelConfig):
@@ -99,9 +105,24 @@ def _add_norm(p, a, x, cfg: ModelConfig):
     """
     if cfg.norm == "layernorm":
         return nn.add_layer_norm(a, x, p["scale"].astype(x.dtype),
-                                 p["bias"].astype(x.dtype))
+                                 p["bias"].astype(x.dtype), **_eps(cfg))
     return nn.add_rms_norm(a, x, p["scale"].astype(x.dtype),
-                           zero_centered=cfg.zero_centered_norm)
+                           zero_centered=cfg.zero_centered_norm, **_eps(cfg))
+
+
+def _multiply(x, factor, name: str):
+    """``x * factor`` as one elementwise op under ``ng:elementwise:<name>``;
+    no op at all where ``factor`` is None (the neutral value)."""
+    if factor is None:
+        return x
+    with jax.named_scope(nn.scope_tag(OpGroup.ELEMENTWISE, name)):
+        return x * jnp.asarray(factor, x.dtype)
+
+
+def _branch(y, cfg: ModelConfig):
+    """A block's attention or FFN branch, scaled by ``residual_multiplier``
+    on its way into the residual add."""
+    return _multiply(y, cfg.residual_multiplier, "residual_multiplier")
 
 
 def _is_moe_layer(cfg: ModelConfig, layer_idx: int) -> bool:
@@ -162,14 +183,14 @@ def block_forward(params, x, cfg: ModelConfig, kind: str, positions,
     h = _apply_norm(params["norm1"], x, cfg)
     a = _mixer_forward(params["mixer"], h, cfg, kind, positions)
     if kind in ("mlstm", "slstm"):
-        return nn.residual_add(x, a), aux
+        return nn.residual_add(x, _branch(a, cfg)), aux
     # manual TP (shard_map bodies): the row-sharded out-projection leaves a
     # partial sum — reduce before anything reads it. No-op otherwise.
     a = nn.tp_psum(a)
     a = checkpoint_name(a, "proj_out")
     if cfg.post_norm:
         a = _apply_norm(params["post_norm1"], a, cfg)
-    h, x = _add_norm(params["norm2"], a, x, cfg)
+    h, x = _add_norm(params["norm2"], _branch(a, cfg), x, cfg)
     # both streams keep the block-boundary constraint the pre-fusion code
     # placed on the sum (h fed the MLP GEMMs from a constrained tensor)
     x = shard(x, "batch", "seq", "embed")
@@ -182,7 +203,7 @@ def block_forward(params, x, cfg: ModelConfig, kind: str, positions,
     f = checkpoint_name(f, "proj_out")
     if cfg.post_norm:
         f = _apply_norm(params["post_norm2"], f, cfg)
-    x = nn.residual_add(x, f)
+    x = nn.residual_add(x, _branch(f, cfg))
     return shard(x, "batch", "seq", "embed"), aux
 
 
@@ -210,11 +231,11 @@ def block_prefill(params, x, cfg: ModelConfig, kind: str, positions,
         a, cache = A.attn_prefill(params["mixer"], h, cfg, kind, positions,
                                   max_len, lengths=lengths)
     if kind in ("mlstm", "slstm"):
-        return nn.residual_add(x, a), cache, aux
+        return nn.residual_add(x, _branch(a, cfg)), cache, aux
     a = nn.tp_psum(a)
     if cfg.post_norm:
         a = _apply_norm(params["post_norm1"], a, cfg)
-    h, x = _add_norm(params["norm2"], a, x, cfg)
+    h, x = _add_norm(params["norm2"], _branch(a, cfg), x, cfg)
     x = shard(x, "batch", "seq", "embed")
     h = shard(h, "batch", "seq", "embed")
     if moe_layer:
@@ -224,7 +245,7 @@ def block_prefill(params, x, cfg: ModelConfig, kind: str, positions,
     f = nn.tp_psum(f)
     if cfg.post_norm:
         f = _apply_norm(params["post_norm2"], f, cfg)
-    x = nn.residual_add(x, f)
+    x = nn.residual_add(x, _branch(f, cfg))
     return shard(x, "batch", "seq", "embed"), cache, aux
 
 
@@ -256,11 +277,11 @@ def block_decode(params, x, cfg: ModelConfig, kind: str, cache, pos,
     else:
         a, cache = A.attn_decode(params["mixer"], h, cfg, kind, cache, pos)
     if kind in ("mlstm", "slstm"):
-        return nn.residual_add(x, a), cache
+        return nn.residual_add(x, _branch(a, cfg)), cache
     a = nn.tp_psum(a)
     if cfg.post_norm:
         a = _apply_norm(params["post_norm1"], a, cfg)
-    h, x = _add_norm(params["norm2"], a, x, cfg)
+    h, x = _add_norm(params["norm2"], _branch(a, cfg), x, cfg)
     if moe_layer:
         f, _ = M.moe_forward(params["moe"], h, cfg)
     else:
@@ -268,7 +289,7 @@ def block_decode(params, x, cfg: ModelConfig, kind: str, cache, pos,
     f = nn.tp_psum(f)
     if cfg.post_norm:
         f = _apply_norm(params["post_norm2"], f, cfg)
-    return nn.residual_add(x, f), cache
+    return nn.residual_add(x, _branch(f, cfg)), cache
 
 
 # ---------------------------------------------------------------------------
@@ -409,6 +430,7 @@ def embed_inputs(params, inputs, cfg: ModelConfig, positions):
         x = x.astype(cfg.activation_dtype)
     else:  # precomputed modality-frontend embeddings (musicgen stub)
         x = inputs.astype(cfg.activation_dtype)
+    x = _multiply(x, cfg.embedding_multiplier, "embedding_multiplier")
     if cfg.scale_embeddings:
         x = nn.scale(x, jnp.asarray(math.sqrt(cfg.d_model), x.dtype))
     return _add_positional(x, positions, params, cfg)
@@ -425,6 +447,8 @@ def logits_from_hidden(params, h, cfg: ModelConfig):
         # explicit .T materializes a vocab x d copy every forward
         logits = nn.einsum("...d,vd->...v", h,
                            params["embed"].astype(h.dtype))
+    if cfg.logits_scaling is not None:
+        logits = _multiply(logits, 1.0 / cfg.logits_scaling, "logits_scaling")
     if cfg.final_logit_softcap:
         c = cfg.final_logit_softcap
         logits = jnp.tanh(logits / c) * c
@@ -651,7 +675,7 @@ def block_extend(params, x, cfg: ModelConfig, kind: str, cache, start,
     a = nn.tp_psum(a)
     if cfg.post_norm:
         a = _apply_norm(params["post_norm1"], a, cfg)
-    h, x = _add_norm(params["norm2"], a, x, cfg)
+    h, x = _add_norm(params["norm2"], _branch(a, cfg), x, cfg)
     if moe_layer:
         f, _ = M.moe_forward(params["moe"], h, cfg)
     else:
@@ -659,7 +683,7 @@ def block_extend(params, x, cfg: ModelConfig, kind: str, cache, start,
     f = nn.tp_psum(f)
     if cfg.post_norm:
         f = _apply_norm(params["post_norm2"], f, cfg)
-    return nn.residual_add(x, f), cache
+    return nn.residual_add(x, _branch(f, cfg)), cache
 
 
 def lm_extend(params, tokens, start, caches, cfg: ModelConfig):

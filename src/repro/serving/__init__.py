@@ -101,6 +101,8 @@ class Request:
     max_new_tokens: int = 32
     output: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # prompt tokens reused from the prefix cache at admission
+    cached_len: int = 0
     # wall-clock timeline (engine clock; seconds)
     enqueue_t: float = 0.0
     admit_t: float = 0.0

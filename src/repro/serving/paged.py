@@ -650,6 +650,7 @@ class PagedEngine(Engine):
             cached_len, reused = 0, []
             if self.prefix_cache is not None:
                 cached_len, reused = self.prefix_cache.lookup(req.prompt)
+            req.cached_len = cached_len
             cold = cached_len == 0 and (self.chunk_size is None
                                         or plen <= self.chunk_size)
             span.set_metadata(cached_len=cached_len,

@@ -34,7 +34,10 @@ def test_flash_jnp_forward(causal, window, rng):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-def test_flash_jnp_vjp_matches_naive(rng):
+@pytest.mark.parametrize("scale", [None, 1 / 128])
+def test_flash_jnp_vjp_matches_naive(rng, scale):
+    """Forward and custom backward both apply the scale (default
+    1/sqrt(Dh); granite's attention_multiplier 1/128)."""
     ks = jax.random.split(rng, 3)
     q = jax.random.normal(ks[0], (1, 40, 4, 16))
     k = jax.random.normal(ks[1], (1, 40, 2, 16))
@@ -42,10 +45,12 @@ def test_flash_jnp_vjp_matches_naive(rng):
 
     def lf(q, k, v):
         return jnp.sum(jnp.cos(A.flash_attention_jnp(
-            q, k, v, causal=True, window=8, chunk_q=16, chunk_kv=8)))
+            q, k, v, causal=True, window=8, chunk_q=16, chunk_kv=8,
+            scale=scale)))
 
     def lr(q, k, v):
-        return jnp.sum(jnp.cos(ref.attention(q, k, v, causal=True, window=8)))
+        return jnp.sum(jnp.cos(ref.attention(q, k, v, causal=True, window=8,
+                                             scale=scale)))
 
     g1 = jax.grad(lf, (0, 1, 2))(q, k, v)
     g2 = jax.grad(lr, (0, 1, 2))(q, k, v)

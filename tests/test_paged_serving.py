@@ -169,6 +169,23 @@ def test_paged_parity_prefix_cache_hits(paged_model):
     assert cold.prefix_cache is None
 
 
+def test_requests_count_the_prompt_tokens_the_cache_served(paged_model):
+    """Each request records how many of its prompt tokens the prefix cache
+    served at admission: the whole blocks it shares with an earlier
+    prompt, and none with the cache off."""
+    cfg, params = paged_model
+    rng = np.random.RandomState(5)
+    prefix = rng.randint(1, cfg.vocab_size, size=27).tolist()
+    prompts = [prefix + rng.randint(1, cfg.vocab_size, size=n).tolist()
+               for n in (6, 3, 9)]
+    for caching, want in ((True, [0, 24, 24]), (False, [0, 0, 0])):
+        eng = mk_paged(cfg, params, max_batch=1, prefix_caching=caching)
+        for prompt in prompts:
+            eng.add_request(prompt, max_new_tokens=2)
+        done = sorted(eng.run(), key=lambda r: r.uid)
+        assert [r.cached_len for r in done] == want
+
+
 def test_paged_eos_frees_blocks_for_refill(paged_model):
     """A request dying at admission (EOS on its first token) must release
     its blocks and refill the slot from the queue in the same pass."""

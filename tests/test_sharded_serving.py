@@ -94,6 +94,17 @@ def test_tp_local_config_shards_heads_kv_ffn_and_pins_head_dim():
     assert local.resolved_head_dim == CFG.resolved_head_dim
 
 
+def test_tp_local_config_keeps_the_scalar_multipliers():
+    from repro.configs import get_config
+
+    cfg = get_config("granite-3-8b")
+    local = tp_mod.tp_local_config(cfg, 4)
+    for f in ("embedding_multiplier", "attention_multiplier",
+              "residual_multiplier", "logits_scaling", "norm_eps"):
+        assert getattr(local, f) == getattr(cfg, f) is not None, f
+    assert local.n_heads == 8 and local.resolved_head_dim == 128
+
+
 def test_tp_local_config_gqa_fallback_keeps_kv_heads():
     # 2 kv heads, tp=4: kv stays replicated, 2 divides the 2 local heads
     cfg = CFG.replace(n_kv_heads=2)
@@ -215,6 +226,15 @@ def test_sharded_paged_kernel_parity(eight_devices):
     inside shard_map and serve what the gathered path serves."""
     out = eight_devices("sharded_serving_check.py", "parity_paged_kernel")
     assert "parity_paged_kernel OK" in out
+
+
+@pytest.mark.multidevice
+def test_sharded_granite_multipliers_parity(eight_devices):
+    """Granite's scalar multipliers and eps reach the TP engines' local
+    config: TP 2 and 4 (paged kernel, prefix hits through lm_extend) serve
+    what one device serves."""
+    out = eight_devices("sharded_serving_check.py", "parity_granite")
+    assert "parity_granite OK" in out
 
 
 @pytest.mark.slow
