@@ -15,7 +15,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 #
 # Usage: python scripts/sharded_serving_check.py \
 #            <parity_decode|parity_paged_kernel|parity_chunked|
-#             parity_prefix|parity_granite|bench>
+#             parity_prefix|parity_granite|parity_lookahead|bench>
 
 import json
 import sys
@@ -31,7 +31,7 @@ from repro.core import get_hardware, model_records
 from repro.core.graph import capture
 from repro.launch.mesh import make_sim_mesh
 from repro.models import init_lm
-from repro.serving import PagedEngine
+from repro.serving import Engine, PagedEngine
 from repro.serving.paged import make_paged_decode_step
 
 ARCH = "stablelm-3b"
@@ -154,6 +154,26 @@ def main(mode: str) -> int:
             f"prefix-hit token streams diverge:\n"
             f"  single: {outs[0]}\n  tp=8:   {outs[1]}")
         print("parity_prefix OK")
+        return 0
+
+    if mode == "parity_lookahead":
+        # the TP engine queues decode step n+1 before it reads step n; over
+        # more requests than slots, chunked prefill and a prefix-hit wave,
+        # its streams must equal the contiguous engine's on one device,
+        # whose decode reads each step before it queues the next
+        cfg, params = cfg_params()
+        base = prompts(6, 18, 32)
+        wave2 = [p[:16] + q for p, q in zip(base, prompts(3, 4, 8, seed=11))]
+        ref = Engine(cfg, params, max_batch=MAX_BATCH, max_len=MAX_LEN)
+        want = (serve(ref, base), serve(ref, wave2))
+        eng = make_engine(2, chunk_size=8)
+        got = (serve(eng, base), serve(eng, wave2))
+        assert eng.prefix_cache.hits > 0
+        assert eng.stats.overlapped_steps > 0
+        assert got == want, (
+            f"tp=2 lookahead streams diverge from the serial engine:\n"
+            f"  serial: {want}\n  tp=2:   {got}")
+        print("parity_lookahead OK")
         return 0
 
     if mode == "bench":

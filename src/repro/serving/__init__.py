@@ -138,6 +138,8 @@ class EngineStats:
     decode_tokens: int = 0            # tokens emitted by decode steps
     first_tokens: int = 0             # tokens emitted by prefill argmax
     decode_steps: int = 0             # jitted decode dispatches
+    overlapped_steps: int = 0         # dispatched before the last was read
+    discarded_tokens: int = 0         # lookahead tokens dropped after EOS
     completed: int = 0                # finished requests
     decoded_requests: int = 0         # completed requests that decoded > 0
     ttft_sum_s: float = 0.0

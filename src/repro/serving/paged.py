@@ -35,12 +35,16 @@ Three mechanisms ride on the block tables:
 Block 0 is reserved as a scratch block: unallocated table entries point at
 it, so cache writes from dead or still-prefilling slots land harmlessly in
 garbage that no masked read ever consumes.
+
+Decode runs one step ahead of the host (``PagedEngine.step``): step n+1 is
+queued, fed with step n's tokens on the device, before step n's tokens are
+read, so the device computes while the host retires, admits and prepares.
 """
 
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -522,9 +526,20 @@ def _scatter_cold_prefill(pools, one, table_row, hi, width: int):
     return _scatter_tree(pools, one, table_row, zero, zero, hi, width)
 
 
+def _merge_tokens(nxt, keep, host):
+    """Step n's device tokens where ``keep``, the host's tokens elsewhere."""
+    return jnp.where(keep, nxt, host)
+
+
 # ---------------------------------------------------------------------------
 # the engine
 # ---------------------------------------------------------------------------
+
+class _InFlight(NamedTuple):
+    """A decode step queued on the device whose tokens are not yet read."""
+    nxt: jax.Array                  # (max_batch,) tokens, on the device
+    rows: Dict[int, Request]        # slot -> request its token goes to
+
 
 class PagedEngine(Engine):
     """Continuous-batching engine over paged KV blocks (vLLM-style).
@@ -603,6 +618,11 @@ class PagedEngine(Engine):
         self._scatter_cold = jax.jit(_scatter_cold_prefill,
                                      static_argnames=("width",),
                                      donate_argnums=(0,))
+        self._merge = jax.jit(_merge_tokens)
+        self._merge_compiled = False
+        #: decode steps queued and not yet read, oldest first (at most two,
+        #: and one between calls: see ``step``)
+        self._inflight: List[_InFlight] = []
 
     # -- bookkeeping -------------------------------------------------------
     def _allocate(self, n: int) -> List[int]:
@@ -789,7 +809,78 @@ class PagedEngine(Engine):
         return None
 
     # -- stepping ----------------------------------------------------------
+    def _ends_by_length(self, req: Request, n: int) -> bool:
+        """Whether ``req`` is done by its budget or the context once it
+        holds ``n`` output tokens (its next write would be at position
+        ``len(prompt) + n - 1``)."""
+        return n >= req.max_new_tokens \
+            or len(req.prompt) + n - 1 >= self.max_len
+
+    def _riding(self) -> Dict[int, Request]:
+        """slot -> request of every slot whose next decode step yields a
+        token it will keep: decoding (not prefilling), and not ended by
+        length once the tokens already in flight for it are counted."""
+        rows = {}
+        for i, r in enumerate(self.slots):
+            if r is None or i in self._prefilling:
+                continue
+            owed = sum(s.rows.get(i) is r for s in self._inflight)
+            if not self._ends_by_length(r, len(r.output) + owed):
+                rows[i] = r
+        return rows
+
+    def _dispatch(self, rows: Dict[int, Request]) -> None:
+        """Queue one decode step on the device for the slots in ``rows``.
+
+        Every other slot is a dead row: pad token, position 0, zeroed table
+        row, so its write lands in the scratch block. A slot that rode in
+        the step queued before, with the same request, is fed that step's
+        token as it sits on the device; the host's token (``_cur``: a first
+        token, or pad) is merged in only when the set of slots changed."""
+        prev = self._inflight[-1] if self._inflight else None
+        ride = np.zeros((self.max_batch,), bool)
+        ride[list(rows)] = True
+        for i in rows:
+            assert self._pos[i] < self.max_len
+            self._ensure_block(i)
+        with self._span("serve.decode.prep", "decode_s"):
+            self.key, k = jax.random.split(self.key)
+            host = np.where(ride, self._cur, self.pad_id).astype(np.int32)
+            if prev is None:
+                cur = jnp.asarray(host)
+            else:
+                keep = np.array([i in rows and prev.rows.get(i) is rows[i]
+                                 for i in range(self.max_batch)])
+                if keep.sum() == len(rows) == len(prev.rows):
+                    cur = prev.nxt          # the same slots and requests
+                else:
+                    cur = self._merge(prev.nxt, keep, host)
+            pos = jnp.asarray(np.where(ride, self._pos, 0))
+            tables = jnp.asarray(np.where(ride[:, None], self._tables, 0))
+        with self._span("serve.decode.dispatch", "decode_s"):
+            nxt, self._pools = self._paged_decode(
+                self.params, cur, pos, self._pools, tables, k)
+        if not self._merge_compiled:
+            # compile the merge on the tokens of the engine's first step:
+            # a step that needs it may come long after any warm-up
+            self._merge(nxt, ride, host)
+            self._merge_compiled = True
+        self._pos[ride] += 1
+        self.stats.decode_steps += 1
+        if prev is not None:
+            self.stats.overlapped_steps += 1
+        self._inflight.append(_InFlight(nxt, rows))
+
+    def _read_tokens(self, step: _InFlight) -> np.ndarray:
+        """Wait for a queued decode step and copy its tokens to the host."""
+        return np.asarray(jax.block_until_ready(step.nxt))
+
     def step(self) -> List[Request]:
+        """Admit, then decode with one step of lookahead: queue decode step
+        n+1 (fed with step n's tokens on the device) before reading step n,
+        so the device computes while the host retires, admits and prepares.
+        With nothing in flight, steps n and n+1 go out back to back. Each
+        call delivers one token per slot that decoded in step n."""
         finished = self._admit_free_slots()
 
         # one chunk per prefilling slot per step (decode-interleaved)
@@ -798,38 +889,50 @@ class PagedEngine(Engine):
             if done is not None:
                 finished.append(done)
 
-        live = [i for i, r in enumerate(self.slots)
-                if r is not None and i not in self._prefilling]
-        if not live:
+        rows = self._riding()
+        if not self._inflight and not rows:
             return finished
-        with self._span("serve.decode", live=len(live), kv=self.kv_path):
-            for i in live:
-                assert self._pos[i] < self.max_len
-                self._ensure_block(i)
-            with self._span("serve.decode.prep", "decode_s"):
-                self.key, k = jax.random.split(self.key)
-                cur, pos = jnp.asarray(self._cur), jnp.asarray(self._pos)
-                tables = jnp.asarray(self._tables)
-            with self._span("serve.decode.dispatch", "decode_s"):
-                nxt, self._pools = self._paged_decode(
-                    self.params, cur, pos, self._pools, tables, k)
+        with self._span("serve.decode", kv=self.kv_path) as decode:
+            if not self._inflight:
+                self._dispatch(rows)
+                rows = self._riding()
+            if rows:
+                self._dispatch(rows)
+            step = self._inflight.pop(0)
+            decode.set_metadata(
+                live=sum(not r.done for r in step.rows.values()),
+                ahead=len(self._inflight))
             with self._span("serve.decode.wait", "decode_s"):
-                nxt_host = np.asarray(jax.block_until_ready(nxt))
-            self.stats.decode_steps += 1
+                nxt_host = self._read_tokens(step)
             with self._span("serve.retire") as span:
                 n = len(finished)
-                for i in live:
-                    r = self.slots[i]
+                for i, r in step.rows.items():
+                    if r.done:
+                        continue        # ended by EOS a step earlier
                     tok = int(nxt_host[i])
                     r.output.append(tok)
                     self.stats.decode_tokens += 1
-                    self._pos[i] += 1
-                    self._cur[i] = tok
-                    if (self.eos_id is not None and tok == self.eos_id) \
-                            or len(r.output) >= r.max_new_tokens \
-                            or self._pos[i] >= self.max_len:
+                    eos = self.eos_id is not None and tok == self.eos_id
+                    if eos or self._ends_by_length(r, len(r.output)):
+                        # A slot ended by length never rides the step in
+                        # flight (_riding counts its owed token). One ended
+                        # by EOS does: that token is thrown away, and the
+                        # step's KV write lands at the slot's next position
+                        # in a block the slot still owned when the step was
+                        # queued. Whoever gets the block next writes or
+                        # masks that row in programs queued after it, and
+                        # decode never writes below len(prompt), so no
+                        # block of the prefix cache is touched.
+                        if any(s.rows.get(i) is r for s in self._inflight):
+                            self.stats.discarded_tokens += 1
                         self._finish(r)
                         finished.append(r)
                         self._free(i)
                 span.set_metadata(finished=len(finished) - n)
+            if self._inflight and all(
+                    r.done for r in self._inflight[0].rows.values()):
+                # every token of the step in flight is thrown away: forget
+                # it (its pools are already the engine's); a later call
+                # starts from the host's tokens
+                self._inflight.clear()
         return finished

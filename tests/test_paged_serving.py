@@ -205,6 +205,186 @@ def test_paged_eos_frees_blocks_for_refill(paged_model):
     assert eng.allocator.free_blocks == eng.allocator.num_blocks - 1
 
 
+# -- one step of lookahead in decode ----------------------------------------
+
+@pytest.fixture(scope="module")
+def varied_model():
+    """The tiny config with its head drawn apart from the embedding: with
+    the tied head and the x12 embedding every greedy token repeats the one
+    before, which would hide a token fed to the wrong step."""
+    cfg = tiny_cfg().replace(tie_embeddings=False)
+    return cfg, init_lm(jax.random.PRNGKey(0), cfg)
+
+
+def _ref_streams(cfg, params, waves, **kw):
+    """The contiguous engine's streams, wave after wave: its decode reads
+    each step's tokens before it queues the next."""
+    eng = Engine(cfg, params, max_batch=kw.get("max_batch", 3),
+                 max_len=kw.get("max_len", 64), eos_id=kw.get("eos_id"))
+    out = {}
+    for wave in waves:
+        for prompt, budget in wave:
+            eng.add_request(prompt, max_new_tokens=budget)
+        out.update({r.uid: r.output for r in eng.run()})
+    return out
+
+
+def _paged_streams(cfg, params, waves, **kw):
+    eng = mk_paged(cfg, params, **kw)
+    out = {}
+    for wave in waves:
+        for prompt, budget in wave:
+            eng.add_request(prompt, max_new_tokens=budget)
+        out.update({r.uid: r.output for r in eng.run()})
+    return eng, out
+
+
+def _lookahead_waves(cfg, case):
+    rng = np.random.RandomState(11)
+
+    def toks(n):
+        return rng.randint(1, cfg.vocab_size, size=n).tolist()
+    if case == "admissions":
+        return [[(toks(int(rng.randint(3, 30))), int(rng.randint(2, 12)))
+                 for _ in range(9)]]
+    if case == "chunked":
+        return [[(toks(n), b) for n, b in
+                 ((40, 6), (5, 9), (23, 3), (33, 7), (9, 5), (17, 4))]]
+    prefix = toks(24)                                   # prefix hits
+    return [[(prefix + toks(5), 6)],
+            [(prefix + toks(n), b) for n, b in ((3, 7), (8, 4), (6, 9))]]
+
+
+@pytest.mark.parametrize("case,kw", [
+    ("admissions", {}),
+    ("chunked", {"chunk_size": 16}),
+    ("prefix", {"chunk_size": 16}),
+])
+def test_lookahead_streams_match_the_serial_engine(varied_model, case, kw):
+    """Decode step n+1 is queued before step n is read; the greedy streams
+    are the contiguous engine's, whose decode is serial, across
+    admissions beyond the slots, chunked prefill and prefix hits."""
+    cfg, params = varied_model
+    waves = _lookahead_waves(cfg, case)
+    want = _ref_streams(cfg, params, waves)
+    eng, got = _paged_streams(cfg, params, waves, **kw)
+    assert got == want
+    assert eng.stats.overlapped_steps > 0
+    assert eng.stats.discarded_tokens == 0
+    if case == "prefix":
+        assert eng.prefix_cache.hits == 3
+    assert eng.allocator.free_blocks + len(eng.prefix_cache) == \
+        eng.allocator.num_blocks - 1
+
+
+def _eos_run(cfg, params, prefix_caching):
+    """Request 1 meets the EOS mid-run (its third decoded token) while
+    requests queue behind it; returns (eos, requests, engine, freed),
+    ``freed`` the blocks each request held when its slot was freed."""
+    rng = np.random.RandomState(12)
+    reqs = [(rng.randint(1, cfg.vocab_size, size=n).tolist(), b)
+            for n, b in ((9, 12), (14, 10), (6, 8), (11, 9), (7, 6))]
+    probe = _ref_streams(cfg, params, [reqs[:1]], max_batch=1)
+    eos = probe[1][3]
+    eng = mk_paged(cfg, params, max_batch=2, eos_id=eos,
+                   prefix_caching=prefix_caching)
+    freed = {}
+    free = eng._free
+
+    def spy(slot):
+        freed[eng.slots[slot].uid] = list(eng._seq_blocks[slot])
+        free(slot)
+    eng._free = spy
+    return eos, reqs, eng, freed
+
+
+@pytest.mark.parametrize("prefix_caching", [False, True])
+def test_eos_discards_the_lookahead_token_and_reuses_its_blocks(
+        varied_model, prefix_caching):
+    """A request that meets the EOS has its next token already queued:
+    that token is thrown away and counted, the blocks the queued step
+    wrote into go to the next request, and every stream is still the one
+    a serial greedy engine gives with the same EOS."""
+    cfg, params = varied_model
+    eos, reqs, eng, freed = _eos_run(cfg, params, prefix_caching)
+    want = _ref_streams(cfg, params, [reqs], max_batch=2, eos_id=eos)
+    for prompt, budget in reqs:
+        eng.add_request(prompt, max_new_tokens=budget)
+    got = {r.uid: r.output for r in eng.run()}
+    assert got == want
+    assert got[1][-1] == eos and len(got[1]) == 4
+    # the EOS ended every request that decoded it before its budget
+    early = [u for u, out in got.items()
+             if len(out) > 1 and out[-1] == eos and len(out) < reqs[u - 1][1]]
+    assert 1 in early
+    assert eng.stats.discarded_tokens == len(early)
+    # a request admitted after request 1 took over one of its blocks
+    later = set().union(*(freed[u] for u in got if u > 2))
+    assert set(freed[1]) & later
+    assert eng.allocator.free_blocks + (len(eng.prefix_cache)
+                                        if prefix_caching else 0) == \
+        eng.allocator.num_blocks - 1
+
+
+#: a backlog whose slots never all end in the same step
+BACKLOG = [(7, 5), (12, 9), (4, 3), (9, 7), (15, 6), (6, 8), (10, 4),
+           (5, 10)]
+
+
+def test_next_step_is_queued_before_the_last_is_read(varied_model):
+    """Over a backlog, every decode step after the first is dispatched
+    while the one before it is unread, and on every step that has a
+    successor that dispatch comes before the read."""
+    cfg, params = varied_model
+    rng = np.random.RandomState(13)
+    eng = mk_paged(cfg, params)
+    log, queued = [], []            # queued: each step's token array
+    decode, read = eng._paged_decode, eng._read_tokens
+
+    def dispatch(*args):
+        out = decode(*args)
+        log.append(("dispatch", len(queued)))
+        queued.append(out[0])
+        return out
+
+    def read_tokens(step):
+        log.append(("read", next(k for k, a in enumerate(queued)
+                                 if a is step.nxt)))
+        return read(step)
+    eng._paged_decode, eng._read_tokens = dispatch, read_tokens
+    for n, budget in BACKLOG:
+        eng.add_request(rng.randint(1, cfg.vocab_size, size=n).tolist(),
+                        max_new_tokens=budget)
+    eng.run()
+    steps = eng.stats.decode_steps
+    assert len(queued) == steps > 1
+    assert eng.stats.overlapped_steps == steps - 1
+    assert [k for what, k in log if what == "read"] == list(range(steps))
+    for k in range(steps - 1):
+        assert log.index(("dispatch", k + 1)) < log.index(("read", k))
+
+
+@pytest.mark.parametrize("eos", [False, True])
+def test_decode_tokens_count_the_tokens_appended(varied_model, eos):
+    """``stats.decode_tokens`` counts the tokens appended to requests,
+    never a thrown-away lookahead token."""
+    cfg, params = varied_model
+    if eos:
+        _, reqs, eng, _ = _eos_run(cfg, params, True)
+    else:
+        rng = np.random.RandomState(13)
+        reqs = [(rng.randint(1, cfg.vocab_size, size=n).tolist(), b)
+                for n, b in BACKLOG]
+        eng = mk_paged(cfg, params)
+    for prompt, budget in reqs:
+        eng.add_request(prompt, max_new_tokens=budget)
+    done = eng.run()
+    assert len(done) == len(reqs)
+    assert eng.stats.decode_tokens == sum(len(r.output) - 1 for r in done)
+    assert eng.stats.first_tokens == len(done)
+    assert (eng.stats.discarded_tokens > 0) == eos
+
+
 # -- paged decode reads the pool in place -----------------------------------
 
 def _streams(cfg, params, reqs, fused, **kw):

@@ -237,6 +237,15 @@ def test_sharded_granite_multipliers_parity(eight_devices):
     assert "parity_granite OK" in out
 
 
+@pytest.mark.multidevice
+def test_sharded_lookahead_parity(eight_devices):
+    """The TP engine queues decode step n+1 before it reads step n: over
+    admissions beyond the slots, chunked prefill and prefix hits its
+    streams equal the serial contiguous engine's on one device."""
+    out = eight_devices("sharded_serving_check.py", "parity_lookahead")
+    assert "parity_lookahead OK" in out
+
+
 @pytest.mark.slow
 @pytest.mark.multidevice
 def test_sharded_chunked_prefill_parity(eight_devices):
