@@ -1,19 +1,26 @@
-"""Serving: continuous-batching KV-cache engine with per-slot positions.
+"""Serving: continuous-batching KV-cache engines with per-slot positions.
 
 ``make_prefill_step`` / ``make_serve_step`` build the two jitted programs the
 dry-run lowers for the inference shapes (prefill_32k lowers prefill;
 decode_32k / long_500k lower serve_step — one new token per slot against a
-seq_len-deep cache, with a vectorized per-slot ``pos``).
+seq_len-deep cache, with a vectorized per-slot ``pos``). Each is a model
+body run by ``_rules_program``: under the mesh's GSPMD rules and
+``nn.fuse(fused)``, on the params cast to the activation dtype. The paged
+step programs (``repro.serving.paged``) take their bodies through the same
+preamble on one chip, or through ``shard_map`` under tensor parallelism.
 
-``Engine`` is the continuous-batching driver used by
-examples/serve_batched.py and the ``serving`` bench section. It keeps a slot
-table of ``max_batch`` sequences over ONE shared KV cache (static shapes:
-TPU serving engines pad the batch, not the program):
+``BaseEngine`` is the host side of a continuous-batching engine: a slot
+table of ``max_batch`` sequences fed from a FIFO queue (static shapes: TPU
+serving engines pad the batch, not the program), the ``EngineStats``
+accounting and the admission bookkeeping. Two engines build on it:
+``Engine`` (here, used by examples/serve_batched.py and the ``serving``
+bench section) over ONE shared contiguous KV cache, and ``PagedEngine``
+(``repro.serving.paged``) over pools of KV blocks.
 
 * admission is per-slot: each request is prefilled alone (right-padded to a
   power-of-two bucket so the prefill program compiles once per bucket, with
-  a length mask picking the last real token's logits) and its caches are
-  written into the shared cache at the slot index via
+  a length mask picking the last real token's logits); ``Engine`` writes
+  its caches into the shared cache at the slot index via
   ``dynamic_update_slice`` — no other slot is disturbed;
 * decode runs one step for the whole slot table with a per-slot position
   vector (``pos: (B,)``), so sequences of different depths coexist;
@@ -23,7 +30,7 @@ TPU serving engines pad the batch, not the program):
 * ``EngineStats`` extends throughput accounting with per-request latency:
   time-to-first-token, queue wait, and per-token decode latency.
 
-Every timed region of the engine is one ``Engine._span``: a
+Every timed region of an engine is one ``BaseEngine._span``: a
 ``jax.profiler`` trace span named ``serve.<region>`` (recorded only while a
 profiler trace runs, on the device planes' clock) that, where it names an
 ``EngineStats`` field, adds the region's time on the engine's ``clock`` to
@@ -40,6 +47,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import inspect
 import time
 from typing import Callable, List, Optional, Sequence
 
@@ -53,6 +61,42 @@ from repro.models.common import ModelConfig
 from repro.runtime import cast_params
 
 
+def _named_like(program: Callable, body: Callable) -> Callable:
+    """Give ``program(params, *args)`` the name and the argument names of
+    ``body(cfg, working, *args)``: jit names the compiled module
+    (``jit_<name>``), every op's ``op_name`` and each parameter by them."""
+    sig = inspect.signature(body)
+    params = inspect.Parameter("params",
+                               inspect.Parameter.POSITIONAL_OR_KEYWORD)
+    program.__name__ = program.__qualname__ = body.__name__
+    program.__signature__ = sig.replace(
+        parameters=[params, *list(sig.parameters.values())[2:]])
+    return program
+
+
+def _rules_program(body: Callable, cfg: ModelConfig, mesh=None,
+                   fused: bool = False) -> Callable:
+    """program(params, *args) -> body(cfg, working, *args), where
+    ``working`` is ``params`` cast to the activation dtype, traced under
+    the mesh's GSPMD rules (``sharding.use_rules``; none without a mesh) and
+    ``nn.fuse(fused)``."""
+    def program(params, *args):
+        with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
+                nn.fuse(fused):
+            working = cast_params(params, cfg.activation_dtype)
+            return body(cfg, working, *args)
+    return _named_like(program, body)
+
+
+def _prefill_body(max_len: int) -> Callable:
+    """The cold-prefill body: (cfg, working, tokens, lengths) ->
+    (last_logits, caches)."""
+    def prefill_step(cfg, working, tokens, lengths=None):
+        return lm_prefill(working, tokens, cfg, max_len=max_len,
+                          lengths=lengths)
+    return prefill_step
+
+
 def make_prefill_step(cfg: ModelConfig, max_len: int, mesh=None,
                       fused: bool = False) -> Callable:
     """prefill_step(params, tokens, lengths=None) -> (last_logits, caches).
@@ -61,13 +105,7 @@ def make_prefill_step(cfg: ModelConfig, max_len: int, mesh=None,
     NonGEMM chains (residual-add→norm, SwiGLU, rope) run as single
     Pallas-kernel-backed fused operators (repro.core.fusion).
     """
-    def prefill_step(params, tokens, lengths=None):
-        with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
-                nn.fuse(fused):
-            working = cast_params(params, cfg.activation_dtype)
-            return lm_prefill(working, tokens, cfg, max_len=max_len,
-                              lengths=lengths)
-    return prefill_step
+    return _rules_program(_prefill_body(max_len), cfg, mesh, fused)
 
 
 def make_serve_step(cfg: ModelConfig, mesh=None,
@@ -79,19 +117,16 @@ def make_serve_step(cfg: ModelConfig, mesh=None,
     ``fused=True`` routes ``lm_decode`` through the fused fast path
     (fused add+norm and SwiGLU — see repro.core.fusion).
     """
-    def serve_step(params, token, pos, caches, key):
-        with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
-                nn.fuse(fused):
-            working = cast_params(params, cfg.activation_dtype)
-            logits, caches = lm_decode(working, token, pos, caches, cfg)
-            lf = logits.astype(jnp.float32)
-            if greedy:
-                nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
-            else:
-                nxt = jax.random.categorical(
-                    key, lf / max(temperature, 1e-3), axis=-1).astype(jnp.int32)
+    def serve_step(cfg, working, token, pos, caches, key):
+        logits, caches = lm_decode(working, token, pos, caches, cfg)
+        lf = logits.astype(jnp.float32)
+        if greedy:
+            nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+        else:
+            nxt = jax.random.categorical(
+                key, lf / max(temperature, 1e-3), axis=-1).astype(jnp.int32)
         return nxt, caches
-    return serve_step
+    return _rules_program(serve_step, cfg, mesh, fused)
 
 
 @dataclasses.dataclass
@@ -201,13 +236,13 @@ def _slot_insert(shared: dict, one: dict, slot) -> dict:
     }
 
 
-class Engine:
-    """Continuous-batching serving engine over one shared static KV cache.
-
-    ``fused=True`` compiles both engine programs (prefill + decode) through
-    the operator-fusion fast path: residual-add→norm pairs and SwiGLU run
-    as single fused Pallas-kernel-backed ops (``repro.core.fusion``),
-    numerically equivalent to the unfused programs.
+class BaseEngine:
+    """The host side of a continuous-batching engine, shared by ``Engine``
+    and ``PagedEngine``: the FIFO request queue, the slot table of
+    ``max_batch`` sequences with each slot's next write position (``_pos``)
+    and next input token (``_cur``), the ``EngineStats`` accounting and its
+    spans, and the admission bookkeeping. Each engine adds its KV store,
+    its programs, ``_admit`` and ``step``.
     """
 
     def __init__(self, cfg: ModelConfig, params, max_batch: int = 8,
@@ -221,6 +256,7 @@ class Engine:
         self.max_batch = max_batch
         self.max_len = max_len
         self.eos_id = eos_id
+        self.mesh = mesh
         self.pad_id = pad_id
         self.greedy = greedy
         self.fused = fused
@@ -229,16 +265,6 @@ class Engine:
         self.queue: List[Request] = []
         self.stats = EngineStats()
         self.clock = clock
-        self._prefill = jax.jit(make_prefill_step(cfg, max_len, mesh,
-                                                  fused=fused))
-        # donate the cache through decode (same as the dry-run's lowering):
-        # the step updates B rows in place instead of copying the cache
-        self._decode = jax.jit(make_serve_step(cfg, mesh, greedy=greedy,
-                                               fused=fused),
-                               donate_argnums=(3,))
-        # donate the shared cache: the splice updates one row in place
-        # instead of copying every (max_batch, max_len, ...) leaf per admit
-        self._insert = jax.jit(_slot_insert, donate_argnums=(0,))
         self._uid = 0
         # recurrent/xLSTM prefill folds every input token — pads included —
         # into its running state, so bucketed right-padding would corrupt
@@ -250,7 +276,6 @@ class Engine:
         self.slots: List[Optional[Request]] = [None] * max_batch
         self._pos = np.zeros((max_batch,), np.int32)
         self._cur = np.full((max_batch,), pad_id, np.int32)
-        self._caches = init_lm_cache(cfg, max_batch, max_len)
 
     # -- queue -------------------------------------------------------------
     def add_request(self, prompt: Sequence[int],
@@ -281,53 +306,51 @@ class Engine:
         self.key, k = jax.random.split(self.key)
         return int(jax.random.categorical(k, lf, axis=-1)[0])
 
-    def _admit(self, slot: int, req: Request) -> bool:
-        """Prefill ``req`` alone and splice it into ``slot``.
-
-        Returns True if the slot is now occupied (False when the request
-        completed at admission: single-token budget or immediate EOS).
+    def _prefill_cold(self, req: Request):
+        """Prefill ``req``'s whole prompt alone through ``self._prefill``,
+        right-padded to its bucket -> (first token, its B=1 caches, bucket).
         """
-        if req.admit_t == 0.0:
-            # first admission only: chunked prefill re-enters _admit-like
-            # paths across several engine steps, and restarting the clock
-            # there would under-report queue wait (and could push the
-            # recorded wait past TTFT). The wait clock runs from submit
-            # (enqueue) to the FIRST admission.
-            req.admit_t = self.clock()
         plen = len(req.prompt)
-        with self._span("serve.admit", uid=req.uid, prompt_len=plen,
-                        cached_len=0, path="cold"):
-            bucket = self._bucket(plen)
-            toks = np.full((1, bucket), self.pad_id, np.int32)
-            toks[0, :plen] = req.prompt      # right-padded
-            with self._span("serve.prefill", "prefill_s", uid=req.uid,
-                            bucket=bucket):
-                logits, one = self._prefill(self.params, jnp.asarray(toks),
-                                            jnp.full((1,), plen, jnp.int32))
-                first = self._first_token(logits)
-            live = not ((self.eos_id is not None and first == self.eos_id)
-                        or req.max_new_tokens <= 1
-                        or plen >= self.max_len)
-            if live:
-                # splice the single-row caches into the slot; block on the
-                # result so this full-cache write is charged to the prefill
-                # phase, not the next decode step's timed region. A request
-                # finishing at admission never needs its caches.
-                with self._span("serve.scatter", "prefill_s", uid=req.uid):
-                    self._caches = self._insert(self._caches, one, slot)
-                    jax.block_until_ready(self._caches)
-            self.stats.prefill_tokens += plen
+        bucket = self._bucket(plen)
+        toks = np.full((1, bucket), self.pad_id, np.int32)
+        toks[0, :plen] = req.prompt      # right-padded
+        with self._span("serve.prefill", "prefill_s", uid=req.uid,
+                        bucket=bucket):
+            logits, one = self._prefill(self.params, jnp.asarray(toks),
+                                        jnp.full((1,), plen, jnp.int32))
+            first = self._first_token(logits)
+        return first, one, bucket
 
-            req.output.append(first)
-            self.stats.first_tokens += 1
-            req.first_token_t = self.clock()
-            if not live:
-                self._finish(req)
-                return False
-            self.slots[slot] = req
-            self._pos[slot] = plen           # next write index == prompt end
-            self._cur[slot] = first
-            return True
+    def _seat(self, slot: int, req: Request, first: int,
+              place: Callable[[], None]) -> bool:
+        """Take a prefilled request's first token and seat it in ``slot``,
+        or finish it.
+
+        The request decodes on unless the token is the EOS, its budget is
+        one token, or its prompt fills the context. If it does, ``place()``
+        first puts its prompt's KV where the slot's decode reads it (a
+        request finishing here never needs its caches). Returns True if the
+        slot is now occupied; False after the request finished and the
+        slot was freed.
+        """
+        plen = len(req.prompt)
+        live = not ((self.eos_id is not None and first == self.eos_id)
+                    or req.max_new_tokens <= 1
+                    or plen >= self.max_len)
+        if live:
+            place()
+        self.stats.prefill_tokens += plen
+        req.output.append(first)
+        self.stats.first_tokens += 1
+        req.first_token_t = self.clock()
+        if not live:
+            self._finish(req)
+            self._free(slot)
+            return False
+        self.slots[slot] = req
+        self._pos[slot] = plen           # next write index == prompt end
+        self._cur[slot] = first
+        return True
 
     def _admit_free_slots(self) -> List[Request]:
         """Fill every free slot from the queue; returns requests that
@@ -336,6 +359,10 @@ class Engine:
         for i in range(self.max_batch):
             while self.queue and self.slots[i] is None:
                 req = self.queue.pop(0)
+                # the wait clock runs from submit (enqueue) to this, the
+                # request's only admission: a chunked prefill spans several
+                # engine steps after it and must not restart the clock
+                req.admit_t = self.clock()
                 if not self._admit(i, req):
                     done.append(req)
         return done
@@ -386,6 +413,61 @@ class Engine:
     def active(self) -> int:
         return sum(r is not None for r in self.slots)
 
+    def run(self) -> List[Request]:
+        """Serve until the queue and the slot table are empty; returns the
+        completed requests in completion order."""
+        finished: List[Request] = []
+        while self.queue or self.active:
+            finished.extend(self.step())
+        return finished
+
+
+class Engine(BaseEngine):
+    """Continuous-batching serving engine over one shared static KV cache.
+
+    Takes ``BaseEngine``'s arguments. ``fused=True`` compiles both engine
+    programs (prefill + decode) through the operator-fusion fast path:
+    residual-add→norm pairs and SwiGLU run as single fused
+    Pallas-kernel-backed ops (``repro.core.fusion``), numerically
+    equivalent to the unfused programs.
+    """
+
+    def __init__(self, cfg: ModelConfig, params, **kw):
+        super().__init__(cfg, params, **kw)
+        self._prefill = jax.jit(make_prefill_step(cfg, self.max_len,
+                                                  self.mesh,
+                                                  fused=self.fused))
+        # donate the cache through decode (same as the dry-run's lowering):
+        # the step updates B rows in place instead of copying the cache
+        self._decode = jax.jit(make_serve_step(cfg, self.mesh,
+                                               greedy=self.greedy,
+                                               fused=self.fused),
+                               donate_argnums=(3,))
+        # donate the shared cache: the splice updates one row in place
+        # instead of copying every (max_batch, max_len, ...) leaf per admit
+        self._insert = jax.jit(_slot_insert, donate_argnums=(0,))
+        self._caches = init_lm_cache(cfg, self.max_batch, self.max_len)
+
+    def _admit(self, slot: int, req: Request) -> bool:
+        """Prefill ``req`` alone and splice it into ``slot``.
+
+        Returns True if the slot is now occupied (False when the request
+        completed at admission: single-token budget or immediate EOS).
+        """
+        with self._span("serve.admit", uid=req.uid,
+                        prompt_len=len(req.prompt), cached_len=0,
+                        path="cold"):
+            first, one, _ = self._prefill_cold(req)
+
+            def splice():
+                # block on the result so this full-cache write is charged
+                # to the prefill phase, not the next decode step's timed
+                # region
+                with self._span("serve.scatter", "prefill_s", uid=req.uid):
+                    self._caches = self._insert(self._caches, one, slot)
+                    jax.block_until_ready(self._caches)
+            return self._seat(slot, req, first, splice)
+
     def step(self) -> List[Request]:
         """One engine iteration: admit, decode one token per live slot,
         retire finished slots (refilled on the next iteration — or by the
@@ -394,7 +476,7 @@ class Engine:
         finished = self._admit_free_slots()
 
         # invariant: every occupied slot has room for its next KV write —
-        # _admit finishes full-context prompts at admission and the decode
+        # _seat finishes full-context prompts at admission and the decode
         # loop below retires a slot the moment its position hits max_len
         assert all(r is None or self._pos[i] < self.max_len
                    for i, r in enumerate(self.slots))
@@ -428,14 +510,6 @@ class Engine:
                         finished.append(r)
                         self._free(i)
                 span.set_metadata(finished=len(finished) - n)
-        return finished
-
-    def run(self) -> List[Request]:
-        """Serve until the queue and the slot table are empty; returns the
-        completed requests in completion order."""
-        finished: List[Request] = []
-        while self.queue or self.active:
-            finished.extend(self.step())
         return finished
 
 

@@ -1,10 +1,17 @@
 """Paged-KV serving: block allocator, prefix cache, chunked prefill.
 
-``PagedEngine`` replaces the contiguous-cache ``Engine``'s single
-``(max_batch, max_len, ...)`` KV cache with a pool of fixed-size KV blocks
-(``(num_blocks, block_size, ...)`` per cache leaf) managed by a free-list
-:class:`BlockAllocator` and addressed through per-sequence block tables —
-the vLLM paging scheme, append-only so no copy-on-write is ever needed.
+``PagedEngine`` shares ``BaseEngine``'s host side (queue, slot table,
+stats, admission bookkeeping) with the contiguous ``Engine``, but in place
+of its single ``(max_batch, max_len, ...)`` KV cache it owns a pool of
+fixed-size KV blocks (``(num_blocks, block_size, ...)`` per cache leaf)
+managed by a free-list :class:`BlockAllocator` and addressed through
+per-sequence block tables — the vLLM paging scheme, append-only so no
+copy-on-write is ever needed.
+
+Its step programs — decode, extend (chunked prefill) and cold prefill —
+are each one model body, which :func:`_step_program` runs on one chip
+or, for a mesh whose ``model`` axis is larger than 1, under manual tensor
+parallelism (``shard_map``, ``repro.models.tp``).
 
 Three mechanisms ride on the block tables:
 
@@ -52,12 +59,13 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import nn, sharding
-from repro.models import init_lm_cache, lm_decode, lm_extend, lm_prefill
+from repro.models import init_lm_cache, lm_decode, lm_extend
 from repro.models import tp as tp_mod
 from repro.models.attention import fit_head
 from repro.models.common import ModelConfig
 from repro.runtime import cast_params
-from repro.serving import Engine, Request, _next_pow2
+from repro.serving import (BaseEngine, Request, _named_like, _next_pow2,
+                           _prefill_body, _rules_program)
 
 
 # ---------------------------------------------------------------------------
@@ -242,23 +250,25 @@ def init_pools(cfg: ModelConfig, num_blocks: int, block_size: int,
         lambda s: jnp.zeros(s.shape[:-1] + (width,), s.dtype), struct)
 
 
+def _map_cache(fn: Callable, *trees) -> dict:
+    """``fn`` over the leaves of cache trees of one structure (pools or
+    caches, ``init_lm_cache``'s ``lead`` / ``scan`` / ``trail`` lists),
+    vmapped over the leading layer dim of the stacked ``scan`` leaves."""
+    def each(part, f):
+        return [jax.tree_util.tree_map(f, *leaves)
+                for leaves in zip(*(t[part] for t in trees))]
+    return {"lead": each("lead", fn), "scan": each("scan", jax.vmap(fn)),
+            "trail": each("trail", fn)}
+
+
 def _gather_tree(pools: dict, tables, max_len: int,
                  head_dim: Optional[int] = None) -> dict:
     """Materialize the contiguous (B, max_len, ...) cache view per leaf;
     with ``head_dim`` (lane-padded pools) each view is cut back to it."""
-    def g0(p):
-        view = nn.paged_kv_gather(p, tables, max_len)
+    def gather(pool):
+        view = nn.paged_kv_gather(pool, tables, max_len)
         return view if head_dim is None else fit_head(view, head_dim)
-
-    def g1(p):                      # scan leaves carry a leading layer dim
-        return jax.vmap(g0)(p)
-
-    tm = jax.tree_util.tree_map
-    return {
-        "lead": [tm(g0, c) for c in pools["lead"]],
-        "scan": [tm(g1, c) for c in pools["scan"]],
-        "trail": [tm(g0, c) for c in pools["trail"]],
-    }
+    return _map_cache(gather, pools)
 
 
 def _view_head_dim(cfg: ModelConfig, fused: bool, tp: int) -> Optional[int]:
@@ -271,178 +281,73 @@ def _view_head_dim(cfg: ModelConfig, fused: bool, tp: int) -> Optional[int]:
 
 def _writeback_tree(pools: dict, caches: dict, tables, pos) -> dict:
     """Scatter each sequence's one new decode row back into its block."""
-    def row(cache):
-        return jax.vmap(
+    def write(pool, cache):
+        row = jax.vmap(
             lambda leaf, p: jax.lax.dynamic_slice_in_dim(leaf, p, 1, axis=0)
         )(cache, pos)
-
-    def w0(pool, cache):
-        return nn.paged_kv_write(pool, row(cache), tables, pos)
-
-    def w1(pool, cache):
-        return jax.vmap(w0)(pool, cache)
-
-    tm = jax.tree_util.tree_map
-    return {
-        "lead": [tm(w0, p, c) for p, c in zip(pools["lead"], caches["lead"])],
-        "scan": [tm(w1, p, c) for p, c in zip(pools["scan"], caches["scan"])],
-        "trail": [tm(w0, p, c)
-                  for p, c in zip(pools["trail"], caches["trail"])],
-    }
+        return nn.paged_kv_write(pool, row, tables, pos)
+    return _map_cache(write, pools, caches)
 
 
 def _scatter_tree(pools: dict, caches: dict, table_row, start, lo, hi,
                   width: int) -> dict:
     """Scatter view rows [start, start + width) of a B=1 cache tree into
     one sequence's blocks (outside [lo, hi) diverts to the scratch block)."""
-    def s0(pool, cache):
+    def scatter(pool, cache):
         rows = jax.lax.dynamic_slice_in_dim(cache[0], start, width, axis=0)
         return nn.paged_kv_scatter(pool, fit_head(rows, pool.shape[-1]),
                                    table_row, start, lo, hi)
-
-    def s1(pool, cache):
-        return jax.vmap(s0)(pool, cache)
-
-    tm = jax.tree_util.tree_map
-    return {
-        "lead": [tm(s0, p, c) for p, c in zip(pools["lead"], caches["lead"])],
-        "scan": [tm(s1, p, c) for p, c in zip(pools["scan"], caches["scan"])],
-        "trail": [tm(s0, p, c)
-                  for p, c in zip(pools["trail"], caches["trail"])],
-    }
+    return _map_cache(scatter, pools, caches)
 
 
 # ---------------------------------------------------------------------------
-# manual tensor parallelism (shard_map: the collectives live in the trace)
+# the step programs: one body each, on one chip or under manual TP
 # ---------------------------------------------------------------------------
 
-def _tp_shard_map(body, mesh, in_specs, out_specs):
-    # check_vma=False: psum-produced outputs defeat static replication
-    # inference (and with it, psum binds as the plain `psum` primitive)
-    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+def _step_program(body: Callable, cfg: ModelConfig, mesh,
+                  fused: bool) -> Callable:
+    """program(params, *args) running ``body(local_cfg, working, *args)``.
 
-
-def _tp_cache_struct_specs(cfg: ModelConfig, max_len: int, tp: int):
-    """PartitionSpec tree matching lm_prefill's returned cache tree (same
-    treedef as ``init_lm_cache`` — specs only need leaf ranks)."""
-    struct = jax.eval_shape(lambda: init_lm_cache(cfg, 1, max_len))
-    return tp_mod.tp_cache_specs(struct, cfg, tp)
-
-
-def _decode_body(cfg: ModelConfig, max_len: int, greedy: bool, kv: str):
-    """(working params, token, pos, pools, tables, key) -> (token', pools')
-    under ``cfg`` (the local config inside a TP shard_map)."""
-    def body(working, token, pos, pools, tables, key):
-        if kv == "paged":
-            logits, pools = lm_decode(working, token, pos, pools, cfg,
-                                      tables=tables)
-        else:
-            caches = _gather_tree(pools, tables, max_len)
-            logits, caches = lm_decode(working, token, pos, caches, cfg)
-            pools = _writeback_tree(pools, caches, tables, pos)
-        lf = logits.astype(jnp.float32)
-        if greedy:
-            nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
-        else:
-            nxt = jax.random.categorical(key, lf, axis=-1).astype(jnp.int32)
-        return nxt, pools
-    return body
-
-
-def _make_tp_paged_decode_step(cfg: ModelConfig, max_len: int, mesh,
-                               tp: int, greedy: bool,
-                               fused: bool, kv: str) -> Callable:
-    """shard_map variant of ``make_paged_decode_step``: every device runs
-    the paged-decode body on its parameter/pool shards under the
-    per-device config, and the per-block ``nn.tp_psum`` reductions (plus
-    the ``nn.tp_vocab_gather`` on a sharded unembedding) become explicit
-    COLLECTIVE primitives in the traced jaxpr."""
-    local = tp_mod.tp_local_config(cfg, tp)
-    vocab = tp_mod.tp_vocab_sharded(cfg, tp)
-    step = _decode_body(local, max_len, greedy, kv)
-
-    def body(params, token, pos, pools, tables, key):
-        with sharding.manual_axis("model", vocab_sharded=vocab), \
-                nn.fuse(fused):
-            working = cast_params(params, local.activation_dtype)
-            return step(working, token, pos, pools, tables, key)
-
-    rep = P()
-
-    def paged_step(params, token, pos, pools, tables, key):
-        pspecs = tp_mod.tp_param_specs(params, cfg, tp)
-        cspecs = tp_mod.tp_cache_specs(pools, cfg, tp)
-        return _tp_shard_map(
-            body, mesh,
-            in_specs=(pspecs, rep, rep, cspecs, rep, rep),
-            out_specs=(rep, cspecs),
-        )(params, token, pos, pools, tables, key)
-    return paged_step
-
-
-def _make_tp_paged_extend_step(cfg: ModelConfig, max_len: int, mesh,
-                               tp: int, fused: bool) -> Callable:
-    """shard_map variant of ``make_paged_extend_step`` (chunked prefill)."""
-    local = tp_mod.tp_local_config(cfg, tp)
-    vocab = tp_mod.tp_vocab_sharded(cfg, tp)
-    head_dim = _view_head_dim(cfg, fused, tp)
-
-    def body(params, tokens, start, pools, table_row, lo, hi):
-        with sharding.manual_axis("model", vocab_sharded=vocab), \
-                nn.fuse(fused):
-            working = cast_params(params, local.activation_dtype)
-            caches = _gather_tree(pools, table_row[None, :], max_len,
-                                  head_dim)
-            logits, caches = lm_extend(working, tokens, start, caches, local)
-            pools = _scatter_tree(pools, caches, table_row, start, lo, hi,
-                                  tokens.shape[1])
-        return logits, pools
-
-    rep = P()
-
-    def extend_step(params, tokens, start, pools, table_row, lo, hi):
-        pspecs = tp_mod.tp_param_specs(params, cfg, tp)
-        cspecs = tp_mod.tp_cache_specs(pools, cfg, tp)
-        return _tp_shard_map(
-            body, mesh,
-            in_specs=(pspecs, rep, rep, cspecs, rep, rep, rep),
-            out_specs=(rep, cspecs),
-        )(params, tokens, start, pools, table_row, lo, hi)
-    return extend_step
-
-
-def make_tp_prefill_step(cfg: ModelConfig, max_len: int, mesh,
-                         fused: bool = False) -> Callable:
-    """shard_map variant of ``serving.make_prefill_step`` for the cold
-    admission path: same signature, but the returned B=1 cache tree is
-    head-sharded (when TP divides ``n_kv_heads``) so it scatters straight
-    into the engine's sharded pools."""
+    ``body`` returns ``(array, cache tree)``; its cache-tree arguments (the
+    only dict arguments: pools, or prefilled B=1 caches) have the
+    structure of ``init_lm_cache``. Without a mesh, or with a ``model``
+    axis of 1, this is ``serving._rules_program``: the body under the
+    mesh's GSPMD rules on the whole model. A larger ``model`` axis selects
+    manual tensor parallelism (``repro.models.tp``): a ``shard_map`` in
+    which every device runs the body on its parameter and KV shards under
+    ``tp_local_config``, so the per-block ``nn.tp_psum`` reductions (and
+    the ``nn.tp_vocab_gather`` of a sharded unembedding) are explicit
+    COLLECTIVE primitives in the traced program. Parameters shard by
+    ``tp_param_specs``, cache trees by ``tp_cache_specs``, everything else
+    replicates.
+    """
     tp = tp_mod.mesh_tp(mesh)
+    if tp <= 1:
+        return _rules_program(body, cfg, mesh, fused)
     local = tp_mod.tp_local_config(cfg, tp)
     vocab = tp_mod.tp_vocab_sharded(cfg, tp)
-    cspecs = _tp_cache_struct_specs(cfg, max_len, tp)
+    # spec trees need only the tree structure and leaf ranks
+    cspecs = tp_mod.tp_cache_specs(
+        jax.eval_shape(lambda: init_lm_cache(cfg, 1, 1)), cfg, tp)
+    rep = P()
 
-    def body(params, tokens, lengths):
+    def shard(params, *args):
         with sharding.manual_axis("model", vocab_sharded=vocab), \
                 nn.fuse(fused):
             working = cast_params(params, local.activation_dtype)
-            return lm_prefill(working, tokens, local, max_len=max_len,
-                              lengths=lengths)
+            return body(local, working, *args)
 
-    rep = P()
-
-    def prefill_step(params, tokens, lengths=None):
-        if lengths is None:
-            lengths = jnp.full((tokens.shape[0],), tokens.shape[1],
-                               jnp.int32)
+    def program(params, *args):
         pspecs = tp_mod.tp_param_specs(params, cfg, tp)
-        return _tp_shard_map(
-            body, mesh,
-            in_specs=(pspecs, rep, rep),
-            out_specs=(rep, cspecs),
-        )(params, tokens, lengths)
-    return prefill_step
+        # check_vma=False: psum-produced outputs defeat static replication
+        # inference (and with it, psum binds as the plain `psum` primitive)
+        return jax.shard_map(
+            shard, mesh=mesh,
+            in_specs=(pspecs,) + tuple(cspecs if isinstance(a, dict)
+                                       else rep for a in args),
+            out_specs=(rep, cspecs), check_vma=False,
+        )(params, *args)
+    return _named_like(program, body)
 
 
 def make_paged_decode_step(cfg: ModelConfig, max_len: int, mesh=None,
@@ -468,24 +373,29 @@ def make_paged_decode_step(cfg: ModelConfig, max_len: int, mesh=None,
       kernel backend.
 
     A mesh whose ``model`` axis is larger than 1 selects the manual-TP
-    shard_map path (see ``repro.models.tp``): bit-identical token streams,
+    shard_map path (:func:`_step_program`): bit-identical token streams,
     explicit COLLECTIVE primitives in the captured program.
     """
-    tp = tp_mod.mesh_tp(mesh)
-    kv = decode_kv_path(cfg, fused, tp)
-    if tp > 1:
-        paged_step = _make_tp_paged_decode_step(cfg, max_len, mesh, tp,
-                                                greedy, fused, kv)
-    else:
-        step = _decode_body(cfg, max_len, greedy, kv)
+    kv = decode_kv_path(cfg, fused, tp_mod.mesh_tp(mesh))
 
-        def paged_step(params, token, pos, pools, tables, key):
-            with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
-                    nn.fuse(fused):
-                working = cast_params(params, cfg.activation_dtype)
-                return step(working, token, pos, pools, tables, key)
-    paged_step.kv = kv
-    return paged_step
+    def paged_step(cfg, working, token, pos, pools, tables, key):
+        if kv == "paged":
+            logits, pools = lm_decode(working, token, pos, pools, cfg,
+                                      tables=tables)
+        else:
+            caches = _gather_tree(pools, tables, max_len)
+            logits, caches = lm_decode(working, token, pos, caches, cfg)
+            pools = _writeback_tree(pools, caches, tables, pos)
+        lf = logits.astype(jnp.float32)
+        if greedy:
+            nxt = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+        else:
+            nxt = jax.random.categorical(key, lf, axis=-1).astype(jnp.int32)
+        return nxt, pools
+
+    program = _step_program(paged_step, cfg, mesh, fused)
+    program.kv = kv
+    return program
 
 
 def make_paged_extend_step(cfg: ModelConfig, max_len: int, mesh=None,
@@ -501,22 +411,16 @@ def make_paged_extend_step(cfg: ModelConfig, max_len: int, mesh=None,
     A mesh with a ``model`` axis larger than 1 selects the manual-TP
     shard_map path, like ``make_paged_decode_step``.
     """
-    tp = tp_mod.mesh_tp(mesh)
-    if tp > 1:
-        return _make_tp_paged_extend_step(cfg, max_len, mesh, tp, fused)
-    head_dim = _view_head_dim(cfg, fused, tp)
+    head_dim = _view_head_dim(cfg, fused, tp_mod.mesh_tp(mesh))
 
-    def extend_step(params, tokens, start, pools, table_row, lo, hi):
-        with sharding.use_rules(mesh, cfg.fsdp, cfg.seq_shard), \
-                nn.fuse(fused):
-            working = cast_params(params, cfg.activation_dtype)
-            caches = _gather_tree(pools, table_row[None, :], max_len,
-                                  head_dim)
-            logits, caches = lm_extend(working, tokens, start, caches, cfg)
-            pools = _scatter_tree(pools, caches, table_row, start, lo, hi,
-                                  tokens.shape[1])
+    def extend_step(cfg, working, tokens, start, pools, table_row, lo, hi):
+        caches = _gather_tree(pools, table_row[None, :], max_len, head_dim)
+        logits, caches = lm_extend(working, tokens, start, caches, cfg)
+        pools = _scatter_tree(pools, caches, table_row, start, lo, hi,
+                              tokens.shape[1])
         return logits, pools
-    return extend_step
+
+    return _step_program(extend_step, cfg, mesh, fused)
 
 
 def _scatter_cold_prefill(pools, one, table_row, hi, width: int):
@@ -541,14 +445,15 @@ class _InFlight(NamedTuple):
     rows: Dict[int, Request]        # slot -> request its token goes to
 
 
-class PagedEngine(Engine):
+class PagedEngine(BaseEngine):
     """Continuous-batching engine over paged KV blocks (vLLM-style).
 
-    Admission paths:
+    Takes ``BaseEngine``'s arguments besides its own. Admission paths:
 
-    * cold prompt, no chunking — the parent's EXACT jitted prefill program
-      runs (guaranteeing first-token bit parity with the contiguous
-      engine), then its single-row cache is scattered into blocks;
+    * cold prompt, no chunking — the whole prompt prefills through the
+      same prefill body as the contiguous ``Engine``'s (on one chip the
+      same program, which keeps first tokens bit-identical to it), then its
+      single-row cache is scattered into blocks;
     * prefix hit / long prompt — decode-interleaved ``lm_extend`` chunks:
       one chunk per engine step, the batch keeps decoding in between.
 
@@ -582,9 +487,6 @@ class PagedEngine(Engine):
         self.prefix_cache = PrefixCache(self.allocator) \
             if prefix_caching else None
         self.chunk_size = chunk_size
-        # the parent's contiguous shared cache is never used (its decode
-        # and insert jits stay untraced — jax.jit is lazy)
-        self._caches = None
         decode = make_paged_decode_step(cfg, max_len, mesh,
                                         greedy=self.greedy, fused=self.fused)
         #: how decode reaches the KV cache, "paged" or "gathered"
@@ -596,8 +498,7 @@ class PagedEngine(Engine):
             # the model axis), head-sharded pools when TP divides
             # n_kv_heads (replicated GQA fallback otherwise). The data
             # axis replicates — block ids are global, so the paged batch
-            # cannot shard. The cold-path prefill must also produce
-            # head-sharded B=1 caches, so swap in the shard_map variant.
+            # cannot shard.
             self.params = jax.device_put(
                 self.params,
                 tp_mod.named_shardings(mesh, tp_mod.tp_param_specs(
@@ -606,11 +507,13 @@ class PagedEngine(Engine):
                 self._pools,
                 tp_mod.named_shardings(mesh, tp_mod.tp_cache_specs(
                     self._pools, cfg, self.tp)))
-            self._prefill = jax.jit(
-                make_tp_prefill_step(cfg, max_len, mesh, fused=self.fused))
         self._tables = np.zeros((max_batch, self.blocks_per_seq), np.int32)
         self._seq_blocks: List[List[int]] = [[] for _ in range(max_batch)]
         self._prefilling: Dict[int, dict] = {}
+        # under TP the prefilled B=1 caches come out head-sharded like the
+        # pools, so they scatter straight in
+        self._prefill = jax.jit(_step_program(_prefill_body(max_len), cfg,
+                                              mesh, self.fused))
         self._paged_decode = jax.jit(decode, donate_argnums=(3,))
         self._paged_extend = jax.jit(
             make_paged_extend_step(cfg, max_len, mesh, fused=self.fused),
@@ -662,8 +565,6 @@ class PagedEngine(Engine):
 
     # -- admission ---------------------------------------------------------
     def _admit(self, slot: int, req: Request) -> bool:
-        if req.admit_t == 0.0:
-            req.admit_t = self.clock()
         plen = len(req.prompt)
         with self._span("serve.admit", uid=req.uid,
                         prompt_len=plen) as span:
@@ -680,45 +581,28 @@ class PagedEngine(Engine):
             return self._start_chunked(slot, req, cached_len, reused)
 
     def _admit_cold(self, slot: int, req: Request) -> bool:
-        """Whole-prompt admission through the parent's prefill program."""
-        plen = len(req.prompt)
-        bucket = self._bucket(plen)
-        toks = np.full((1, bucket), self.pad_id, np.int32)
-        toks[0, :plen] = req.prompt
-        with self._span("serve.prefill", "prefill_s", uid=req.uid,
-                        bucket=bucket):
-            logits, one = self._prefill(self.params, jnp.asarray(toks),
-                                        jnp.full((1,), plen, jnp.int32))
-            first = self._first_token(logits)
-        live = not ((self.eos_id is not None and first == self.eos_id)
-                    or req.max_new_tokens <= 1
-                    or plen >= self.max_len)
-        if live:
-            n = -(-plen // self.block_size)
+        """Whole-prompt admission: prefill, then scatter into new blocks."""
+        first, one, bucket = self._prefill_cold(req)
+
+        def scatter():
+            n = -(-len(req.prompt) // self.block_size)
             with self._span("serve.scatter", "prefill_s", uid=req.uid,
                             blocks=n):
                 blocks = self._allocate(n)
                 self._seq_blocks[slot] = blocks
-                self._tables[slot, :] = 0
-                self._tables[slot, :len(blocks)] = blocks
+                self._set_table(slot, blocks)
                 self._pools = self._scatter_cold(
                     self._pools, one, jnp.asarray(self._tables[slot]),
-                    jnp.int32(plen), width=bucket)
+                    jnp.int32(len(req.prompt)), width=bucket)
                 jax.block_until_ready(self._pools)
                 if self.prefix_cache is not None:
                     self.prefix_cache.insert(req.prompt, blocks)
-        self.stats.prefill_tokens += plen
+        return self._seat(slot, req, first, scatter)
 
-        req.output.append(first)
-        self.stats.first_tokens += 1
-        req.first_token_t = self.clock()
-        if not live:
-            self._finish(req)
-            return False
-        self.slots[slot] = req
-        self._pos[slot] = plen
-        self._cur[slot] = first
-        return True
+    def _set_table(self, slot: int, blocks: List[int]) -> None:
+        """Point ``slot``'s global block-table row at ``blocks``."""
+        self._tables[slot, :] = 0
+        self._tables[slot, :len(blocks)] = blocks
 
     def _chunk_plan(self, cached: int, plen: int) -> List[Tuple[int, int]]:
         """-> [(start, width)] covering [cached, plen); never overlaps the
@@ -787,26 +671,14 @@ class PagedEngine(Engine):
             jax.block_until_ready(self._pools)
         if not last:
             return None
-        self.stats.prefill_tokens += plen
         del self._prefilling[slot]
-
-        req.output.append(first)
-        self.stats.first_tokens += 1
-        req.first_token_t = self.clock()
+        # registered before a finishing request frees its blocks
         if self.prefix_cache is not None:
             self.prefix_cache.insert(req.prompt, st["blocks"])
-        live = not ((self.eos_id is not None and first == self.eos_id)
-                    or req.max_new_tokens <= 1
-                    or plen >= self.max_len)
-        if not live:
-            self._finish(req)
-            self._free(slot)
-            return req
-        self._tables[slot, :] = 0
-        self._tables[slot, :len(st["blocks"])] = st["blocks"]
-        self._pos[slot] = plen
-        self._cur[slot] = first
-        return None
+        if self._seat(slot, req, first,
+                      lambda: self._set_table(slot, st["blocks"])):
+            return None
+        return req
 
     # -- stepping ----------------------------------------------------------
     def _ends_by_length(self, req: Request, n: int) -> bool:

@@ -456,6 +456,33 @@ def test_paged_step_gathers_only_off_the_kernel_path(case):
     assert ("ng:fused:fused_attn_decode" in text) == (case != "unfused")
 
 
+def test_engine_programs_keep_their_module_names(paged_model):
+    """The device trace finds the engine's step programs by their jitted
+    module names: decode, extend, cold prefill and its scatter, as the
+    fused engine on a kernel backend builds them."""
+    cfg, params = paged_model
+    with nn.backend("pallas_interpret"):
+        eng = mk_paged(cfg, params, fused=True)
+        assert eng.kv_path == "paged"
+        i32 = jnp.int32(0)
+        row = jnp.asarray(eng._tables[0])
+        toks = jnp.zeros((1, 16), jnp.int32)
+        lengths = jnp.ones((1,), jnp.int32)
+        one = jax.eval_shape(eng._prefill, eng.params, toks, lengths)[1]
+        lowered = {
+            "paged_step": eng._paged_decode.lower(
+                eng.params, jnp.asarray(eng._cur), jnp.asarray(eng._pos),
+                eng._pools, jnp.asarray(eng._tables), eng.key),
+            "extend_step": eng._paged_extend.lower(
+                eng.params, toks, i32, eng._pools, row, i32, i32),
+            "prefill_step": eng._prefill.lower(eng.params, toks, lengths),
+            "_scatter_cold_prefill": eng._scatter_cold.lower(
+                eng._pools, one, row, i32, width=16),
+        }
+    for name, low in lowered.items():
+        assert low.as_text().startswith(f"module @jit_{name} "), name
+
+
 def test_paged_rejects_non_attention_mixers():
     cfg = reduced(get_config("recurrentgemma-2b")).replace(loss_chunk=0)
     params = init_lm(jax.random.PRNGKey(0), cfg)
